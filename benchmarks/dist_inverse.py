@@ -37,13 +37,13 @@ import os
 _NDEV = int(os.environ.get("REPRO_DI_DEVICES", "4"))
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d" % _NDEV)
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-import repro.compat
 from benchmarks.common import timed
 from repro.configs import get_smoke_config
 from repro.core.kfac import KFACConfig
@@ -128,13 +128,13 @@ import os
 _NDEV = int(os.environ.get("REPRO_DI_DEVICES", "4"))
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d" % _NDEV)
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-import repro.compat
 from benchmarks.common import timed
 from repro.core.kfac import KFACConfig
 from repro.solve import (SMWConfig, invert_factor_tree, pdiv_invert,

@@ -35,6 +35,7 @@ if _NDEV < 512:
     _NDEV = max(2, _NDEV - (_NDEV % 2))
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d" % _NDEV)
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json
 import jax
 import jax.numpy as jnp
@@ -51,7 +52,8 @@ if _NDEV >= 512:
     mesh = make_production_mesh(multi_pod=True)
 else:
     # reduced probe: keep the DCN-crossing pod axis, shrink the rest
-    mesh = jax.make_mesh((2, _NDEV // 2, 1), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, _NDEV // 2, 1), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 cfg = get_config(os.environ.get("REPRO_GC_ARCH", "llama3.2-1b"))
 params = steps_mod.abstract_params(cfg)
 pshard = shard_rules.param_sharding(params, mesh)

@@ -42,6 +42,7 @@ import os
 _NDEV = int(os.environ.get("REPRO_PB_DEVICES", "4"))
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d" % _NDEV)
+os.environ["JAX_PLATFORMS"] = "cpu"
 import dataclasses
 import json
 
@@ -49,7 +50,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-import repro.compat
 from benchmarks.common import timed
 from repro.configs import get_smoke_config
 from repro.core import kfac as kfac_mod

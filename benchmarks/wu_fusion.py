@@ -205,14 +205,13 @@ def rows(archs=ARCHS + EXTRA_ARCHS):
 _CHILD = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-import repro.compat
 from benchmarks.common import timed
 from repro.configs import get_smoke_config
 from repro.core import kfac

@@ -1,8 +1,2 @@
 """RePAST reproduction: second-order (K-FAC) training with
-composed-precision block inversion, grown into a sharded jax system.
-
-Importing any ``repro.*`` module installs the jax API backfills in
-:mod:`repro.compat` (newer API spellings on older jaxlibs).
-"""
-
-from repro import compat as _compat  # noqa: F401  (side-effect import)
+composed-precision block inversion, grown into a sharded jax system."""

@@ -362,8 +362,11 @@ def composed_inverse(
     4. Loop x (iterative refinement on the inverse): ``M <- M + M(I - A M)``
        recovering the bits the low-precision primitive lost.
 
-    Returns an fp32 inverse accurate to ~2^-20 relative for damped SOI
-    blocks while all O(n^3) work is bf16.
+    Returns an fp32 inverse while all O(n^3) work is bf16. Its accuracy
+    is bounded by the 16 significand bits of the bf16 hi/lo operands:
+    on 64 damped 128x128 blocks at relative damping 0.03 it reads 15.09
+    bits of max-relative error against a float64 inverse, on a TPU v5e
+    and on the CPU alike (``chip_smoke.py``'s precision phase).
     """
     A = A.astype(jnp.float32)
     n = A.shape[-1]

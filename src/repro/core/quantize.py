@@ -119,6 +119,24 @@ def reconstruct_slices(
 # TPU production path: hi/lo decomposition in bf16 ("bit-slicing" for the MXU)
 # ---------------------------------------------------------------------------
 
+def round_bf16(x: jax.Array) -> jax.Array:
+    """fp32 ``x`` rounded to bf16 (nearest-even), kept in fp32.
+
+    Rounded on the bits and never as a bf16 round trip: XLA may fold an
+    ``f32 -> bf16 -> f32`` convert pair to the identity (excess precision
+    is allowed by default), and on a TPU it does, so ``x - hi`` and every
+    lo slice built from it become zero. Integer ops lower both in XLA and
+    in Mosaic, which has no ``reduce_precision``, so the Pallas kernels
+    split with this same function. Bitwise the nearest-even conversion
+    for every non-NaN input (a NaN may come out as inf; its lo slice
+    stays NaN).
+    """
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    lsb = jax.lax.shift_right_logical(bits, 16) & 1
+    bits = (bits + (0x7FFF + lsb)) & ~0xFFFF
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 def split_hi_lo_bf16(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Split an fp32 array into two bf16 arrays such that
     ``hi + lo ≈ x`` with ~16 mantissa bits of effective precision.
@@ -129,9 +147,16 @@ def split_hi_lo_bf16(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     (near-)fp32 precision.
     """
     x = x.astype(jnp.float32)
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
+    hi = round_bf16(x)
+    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
+
+
+def _dot(x, y, lhs_contract, precision=None):
+    """``x`` contracted on ``lhs_contract`` with ``y``'s first dim,
+    accumulated in fp32."""
+    return jax.lax.dot_general(
+        x, y, (((lhs_contract,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
 
 
 def hilo_matmul(a: jax.Array, b: jax.Array, *, precision=None) -> jax.Array:
@@ -144,13 +169,18 @@ def hilo_matmul(a: jax.Array, b: jax.Array, *, precision=None) -> jax.Array:
     """
     a_hi, a_lo = split_hi_lo_bf16(a)
     b_hi, b_lo = split_hi_lo_bf16(b)
+    k = a.ndim - 1
+    return (_dot(a_hi, b_hi, k, precision) + _dot(a_hi, b_lo, k, precision)
+            + _dot(a_lo, b_hi, k, precision))
 
-    def mm(x, y):
-        return jax.lax.dot_general(
-            x, y, (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
 
-    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
+def hilo_matmul_tn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a.T @ b`` for 2-D operands as the three partial products of
+    :func:`hilo_matmul`, contracting the leading dims (a Gram
+    accumulation without a transpose)."""
+    a_hi, a_lo = split_hi_lo_bf16(a)
+    b_hi, b_lo = split_hi_lo_bf16(b)
+    return _dot(a_hi, b_hi, 0) + _dot(a_hi, b_lo, 0) + _dot(a_lo, b_hi, 0)
 
 
 def hilo_matmul_exact_lhs(a16: jax.Array, b: jax.Array, *,
@@ -162,13 +192,8 @@ def hilo_matmul_exact_lhs(a16: jax.Array, b: jax.Array, *,
     against a hi-slice operand)."""
     b_hi, b_lo = split_hi_lo_bf16(b)
     a16 = a16.astype(jnp.bfloat16)
-
-    def mm(x, y):
-        return jax.lax.dot_general(
-            x, y, (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
-
-    return mm(a16, b_hi) + mm(a16, b_lo)
+    k = a16.ndim - 1
+    return _dot(a16, b_hi, k, precision) + _dot(a16, b_lo, k, precision)
 
 
 # ---------------------------------------------------------------------------
@@ -218,9 +243,9 @@ def split_limbs_bf16(x: jax.Array, limbs: int = 3) -> list[jax.Array]:
     r = x.astype(jnp.float32)
     out = []
     for _ in range(limbs):
-        l = r.astype(jnp.bfloat16)
-        out.append(l)
-        r = r - l.astype(jnp.float32)
+        l = round_bf16(r)
+        out.append(l.astype(jnp.bfloat16))
+        r = r - l
     return out
 
 
@@ -286,15 +311,17 @@ def lowp_einsum(spec: str, a: jax.Array, b: jax.Array, *,
                 precision: str = "fp32") -> jax.Array:
     """The WU graph's single matmul routing point.
 
-    ``precision="fp32"`` is *bitwise identical* to
-    ``jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)`` — the
-    default path through :mod:`core.soi` / :mod:`solve.fused_wu` is
-    unchanged. ``"hilo"`` routes through bf16 limb products,
-    ``"int8"`` / ``"int<T>b<S>"`` through the sliced integer product.
+    ``precision="fp32"`` is ``jnp.einsum(spec, a, b,
+    preferred_element_type=jnp.float32)`` pinned to ``HIGHEST``: a TPU
+    runs an unpinned fp32 einsum as one bf16 pass (about 8 bits), the
+    CPU ignores the pin (bitwise the historical einsum). ``"hilo"``
+    routes through bf16 limb products, ``"int8"`` / ``"int<T>b<S>"``
+    through the sliced integer product.
     """
     kind = precision_kind(precision)
     if kind == "fp32":
-        return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+        return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
     if kind == "hilo":
         return hilo_einsum(spec, a, b)
     total, sl = kind

@@ -31,8 +31,6 @@ from typing import Any, Optional, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import active_mesh
-
 POD = "pod"
 STAGE = "stage"
 DATA = "data"
@@ -171,6 +169,15 @@ def _norm_entry(entry) -> Tuple[str, ...]:
     if isinstance(entry, str):
         return (entry,)
     return tuple(entry)
+
+
+def active_mesh():
+    """The mesh in scope (``jax.set_mesh``), or None: the single place
+    :func:`shard_hint` and :func:`shard_like_params` consult."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or m.empty:
+        return None
+    return m
 
 
 def clean_spec(spec, shape, mesh) -> P:

@@ -27,13 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.quantize import hilo_matmul
+
 __all__ = ["bitslice_mm"]
-
-
-def _split(x: jax.Array):
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref):
@@ -41,14 +37,8 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a_hi, a_lo = _split(a_ref[...])
-    b_hi, b_lo = _split(b_ref[...])
-
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32)
-
     # three bf16 MXU partial products, shift-added in the fp32 accumulator
-    acc_ref[...] += mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
+    acc_ref[...] += hilo_matmul(a_ref[...], b_ref[...])
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _flush():

@@ -31,34 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.quantize import (
+    hilo_matmul, hilo_matmul_exact_lhs, hilo_matmul_tn, split_hi_lo_bf16)
+from repro.kernels.vmem import VMEM_LIMIT_BYTES
+
 __all__ = ["fused_gram_inv"]
-
-
-def _split(x):
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
-
-
-def _hilo_mm(a, b):
-    a_hi, a_lo = _split(a)
-    b_hi, b_lo = _split(b)
-
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32)
-
-    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
-
-
-def _hilo_mm_exact(a16, b):
-    """lhs exactly bf16: two partial products (§Perf 3.1)."""
-    b_hi, b_lo = _split(b)
-    a16 = a16.astype(jnp.bfloat16)
-
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32)
-
-    return mm(a16, b_hi) + mm(a16, b_lo)
 
 
 def _kernel(a_ref, o_ref, gram_ref, *, n, n_true, n_tok, rel_damp,
@@ -68,15 +45,8 @@ def _kernel(a_ref, o_ref, gram_ref, *, n, n_true, n_tok, rel_damp,
         gram_ref[...] = jnp.zeros_like(gram_ref)
 
     # Gram accumulation: one (bt, n) activation tile -> rank-bt update.
-    a_t = a_ref[:, 0, :]                             # (bt, n) fp32
-    a_hi, a_lo = _split(a_t)
-
-    def mm_t(x, y):
-        return jax.lax.dot_general(
-            x, y, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    gram_ref[...] += mm_t(a_hi, a_hi) + mm_t(a_hi, a_lo) + mm_t(a_lo, a_hi)
+    a_t = a_ref[...]                                 # (bt, n) fp32
+    gram_ref[...] += hilo_matmul_tn(a_t, a_t)
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _invert():
@@ -86,31 +56,30 @@ def _kernel(a_ref, o_ref, gram_ref, *, n, n_true, n_tok, rel_damp,
         # n_true, not the padded width (padding columns are zero).
         lam = rel_damp * jnp.trace(g) / jnp.float32(n_true) + 1e-8
         a = g + lam * eye
-        a_h16 = a.astype(jnp.bfloat16)
+        a_h16, a_l16 = split_hi_lo_bf16(a)
         a_h = a_h16.astype(jnp.float32)
-        a_l16 = (a - a_h).astype(jnp.bfloat16)
 
         n1 = jnp.max(jnp.sum(jnp.abs(a_h), axis=0))
         ninf = jnp.max(jnp.sum(jnp.abs(a_h), axis=1))
         x = a_h / (n1 * ninf)
 
         def ns_body(_, x):
-            ax = _hilo_mm_exact(a_h16, x)
-            return _hilo_mm(x, 2.0 * eye - ax)
+            ax = hilo_matmul_exact_lhs(a_h16, x)
+            return hilo_matmul(x, 2.0 * eye - ax)
 
         x = jax.lax.fori_loop(0, ns_iters, ns_body, x)
 
         def taylor_body(_, carry):
             m, t = carry
-            t = -_hilo_mm(x, _hilo_mm_exact(a_l16, t))
+            t = -hilo_matmul(x, hilo_matmul_exact_lhs(a_l16, t))
             return m + t, t
 
         m, _ = jax.lax.fori_loop(0, max(taylor_terms - 1, 0),
                                  taylor_body, (x, x))
 
         def refine_body(_, m):
-            r = eye - _hilo_mm(a, m)
-            return m + _hilo_mm(m, r)
+            r = eye - hilo_matmul(a, m)
+            return m + hilo_matmul(m, r)
 
         m = jax.lax.fori_loop(0, refine_steps, refine_body, m)
         o_ref[0] = m
@@ -138,6 +107,9 @@ def fused_gram_inv(
     computed without materializing any Gram in HBM.
     """
     t, nb, n = a.shape
+    if bt % 8:
+        raise ValueError(f"bt={bt} must be a multiple of 8 (the TPU's "
+                         f"sublane tiling)")
     n_pad = max(128, (-(-n // 128)) * 128)
     t_pad = (-t) % bt
     a_p = jnp.pad(a.astype(jnp.float32),
@@ -145,6 +117,9 @@ def fused_gram_inv(
     # padded feature columns produce zero Gram rows/cols; identity-damp
     # them inside the kernel via lam*I so the block stays invertible.
     tp = a_p.shape[0]
+    # (T, nb, n) -> (T, nb * n) is free (row-major), and block i of the
+    # columns is feature slab i: a (bt, n_pad) block tiles as (8, 128)
+    a_p = a_p.reshape(tp, nb * n_pad)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n=n_pad, n_true=n, n_tok=t,
@@ -153,13 +128,14 @@ def fused_gram_inv(
                           refine_steps=refine_steps),
         grid=(nb, tp // bt),
         in_specs=[
-            pl.BlockSpec((bt, 1, n_pad), lambda i, k: (k, i, 0)),
+            pl.BlockSpec((bt, n_pad), lambda i, k: (k, i)),
         ],
         out_specs=pl.BlockSpec((1, n_pad, n_pad), lambda i, k: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, n_pad, n_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n_pad, n_pad), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(a_p)

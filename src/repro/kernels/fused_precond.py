@@ -30,36 +30,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.quantize import hilo_matmul
+from repro.kernels.vmem import VMEM_LIMIT_BYTES
+
 __all__ = ["fused_precond"]
-
-
-def _split(x):
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
-
-
-def _hilo_mm(a, b):
-    """bf16-operand fp32-accumulate matmul (three partial products)."""
-    a_hi, a_lo = _split(a)
-    b_hi, b_lo = _split(b)
-
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32)
-
-    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
 
 
 def _kernel(a_ref, g_ref, gi_ref, o_ref, dot_ref):
     g = g_ref[0]
     # left VMM (A-side INV feed), intermediate stays in VMEM
-    tmp = _hilo_mm(a_ref[0], g)
+    tmp = hilo_matmul(a_ref[0], g)
     # right VMM (G-side INV feed)
-    out = _hilo_mm(tmp, gi_ref[0])
+    out = hilo_matmul(tmp, gi_ref[0])
     o_ref[0] = out
     # trust-region contribution of this tile, same pass: gradient pad
-    # rows/cols are zero, so the padded dot equals the unpadded one
-    dot_ref[0, 0] = jnp.sum(out * g)
+    # rows/cols are zero, so the padded dot equals the unpadded one.
+    # Written across one (1, 128) lane row: a scalar block would not
+    # satisfy the TPU's (8, 128) block tiling.
+    dot_ref[...] = jnp.full(dot_ref.shape, jnp.sum(out * g), jnp.float32)
 
 
 def _pad2(x, r, c):
@@ -103,16 +91,16 @@ def fused_precond(
         ],
         out_specs=[
             pl.BlockSpec((1, bi_p, bo_p), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, bi_p, bo_p), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, 128), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(a_p, g_p, gi_p)
-    return out[:, :bi, :bo], dots[:, 0]
+    return out[:, :bi, :bo], dots[:, 0, 0]

@@ -38,36 +38,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.quantize import (
+    hilo_matmul, hilo_matmul_exact_lhs, split_hi_lo_bf16)
+from repro.kernels.vmem import VMEM_LIMIT_BYTES
+
 __all__ = ["neumann_inv"]
-
-
-def _split(x):
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
-
-
-def _hilo_mm(a, b):
-    """bf16-operand fp32-accumulate matmul (three partial products)."""
-    a_hi, a_lo = _split(a)
-    b_hi, b_lo = _split(b)
-
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32)
-
-    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
-
-
-def _hilo_mm_exact(a16, b):
-    """lhs exactly bf16 (hi/lo slice): two partial products suffice
-    (EXPERIMENTS.md §Perf 3.1)."""
-    b_hi, b_lo = _split(b)
-    a16 = a16.astype(jnp.bfloat16)
-
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32)
-
-    return mm(a16, b_hi) + mm(a16, b_lo)
 
 
 def _kernel(a_ref, damp_ref, o_ref, *, n, ns_iters, taylor_terms,
@@ -75,10 +50,9 @@ def _kernel(a_ref, damp_ref, o_ref, *, n, ns_iters, taylor_terms,
     eye = jnp.eye(n, dtype=jnp.float32)
     # Damped block: A + lam*I (Tikhonov, paper Sec. III-A.3). Padding rows
     # get the identity so the padded block stays invertible.
-    a = a_ref[0] + damp_ref[0, 0] * eye
-    a_hi16 = a.astype(jnp.bfloat16)
+    a = a_ref[0] + jnp.max(damp_ref[0]) * eye
+    a_hi16, a_lo16 = split_hi_lo_bf16(a)
     a_hi = a_hi16.astype(jnp.float32)
-    a_lo16 = (a - a_hi).astype(jnp.bfloat16)
 
     # ||A||_2 upper bound: sqrt(||A||_1 ||A||_inf); X0 = A_H / bound^2.
     n1 = jnp.max(jnp.sum(jnp.abs(a_hi), axis=0))
@@ -88,15 +62,15 @@ def _kernel(a_ref, damp_ref, o_ref, *, n, ns_iters, taylor_terms,
     # (2) low-precision INV primitive: Newton-Schulz  X <- X(2I - A_H X)
     # (A_H exactly bf16 => two-partial products, §Perf 3.1)
     def ns_body(_, x):
-        ax = _hilo_mm_exact(a_hi16, x)
-        return _hilo_mm(x, 2.0 * eye - ax)
+        ax = hilo_matmul_exact_lhs(a_hi16, x)
+        return hilo_matmul(x, 2.0 * eye - ax)
 
     x = jax.lax.fori_loop(0, ns_iters, ns_body, x)
 
     # (3) Loop A: Neumann series  M = sum_l (-Y A_L)^l Y   (Eqn. 9)
     def taylor_body(_, carry):
         m, t = carry
-        t = -_hilo_mm(x, _hilo_mm_exact(a_lo16, t))
+        t = -hilo_matmul(x, hilo_matmul_exact_lhs(a_lo16, t))
         return m + t, t
 
     m, _ = jax.lax.fori_loop(0, max(taylor_terms - 1, 0), taylor_body,
@@ -104,8 +78,8 @@ def _kernel(a_ref, damp_ref, o_ref, *, n, ns_iters, taylor_terms,
 
     # (4) Loop x analogue: refinement against the full-precision A.
     def refine_body(_, m):
-        r = eye - _hilo_mm(a, m)
-        return m + _hilo_mm(m, r)
+        r = eye - hilo_matmul(a, m)
+        return m + hilo_matmul(m, r)
 
     m = jax.lax.fori_loop(0, refine_steps, refine_body, m)
     o_ref[0] = m
@@ -157,7 +131,9 @@ def neumann_inv(
         raise ValueError(
             f"damping must be a scalar or shape ({nb},) to match the "
             f"{nb} blocks; got shape {damp.shape}")
-    damp = damp.reshape(nb, 1)
+    # one (1, 128) lane row per block: a scalar block would not satisfy
+    # the TPU's (8, 128) block tiling
+    damp = jnp.broadcast_to(damp.reshape(nb, 1, 1), (nb, 1, 128))
 
     out = pl.pallas_call(
         functools.partial(_kernel, n=n_pad, ns_iters=ns_iters,
@@ -166,13 +142,13 @@ def neumann_inv(
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, n_pad, n_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, n_pad, n_pad), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, n_pad, n_pad), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(a_p, damp)

@@ -1,16 +1,16 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On a real TPU backend the kernels compile to Mosaic; everywhere else
-(this CPU container, unit tests) they run under ``interpret=True``,
-which executes the same kernel body per-block in Python — bit-identical
-block decomposition, so CPU validation covers the TPU tiling logic.
+On a TPU backend the kernels compile to Mosaic; on any other backend
+(the CPU test suite) they run under ``interpret=True``, which executes
+the same kernel body block by block in Python. The wrappers decide the
+mode from the backend alone, so a TPU run never falls back to the
+interpreter.
 
-``use_pallas_inverses()`` lets the K-FAC optimizer swap its SOI block
-inversion onto the kernel path (TPU production); the default JAX path
-(`core.precision_inv.composed_inverse`) is numerically the same
-algorithm and is what the multi-pod dry-run lowers (Pallas TPU kernels
-cannot lower for the CPU stand-in devices; the FLOP/byte structure XLA
-reports is identical).
+No default K-FAC path calls a kernel: ``precondition(use_kernel=True)``
+(``fused_precond``) and ``SMWConfig(use_kernel=True)`` (``smw_update``)
+opt in; the composed-precision SOI inversion runs as plain JAX
+(``core.precision_inv.composed_inverse``), the same algorithm as
+``neumann_inv``.
 """
 
 from __future__ import annotations
@@ -33,27 +33,24 @@ def on_tpu() -> bool:
 
 
 def bitslice_mm(a: jax.Array, b: jax.Array, **kw) -> jax.Array:
-    kw.setdefault("interpret", not on_tpu())
-    return _bitslice_mm(a, b, **kw)
+    return _bitslice_mm(a, b, interpret=not on_tpu(), **kw)
 
 
 def neumann_inv(a: jax.Array, damping, **kw) -> jax.Array:
-    kw.setdefault("interpret", not on_tpu())
-    return _neumann_inv(a, jnp.asarray(damping), **kw)
+    return _neumann_inv(a, jnp.asarray(damping), interpret=not on_tpu(),
+                        **kw)
 
 
 def fused_gram_inv(a: jax.Array, **kw) -> jax.Array:
-    kw.setdefault("interpret", not on_tpu())
-    return _fused_gram_inv(a, **kw)
+    return _fused_gram_inv(a, interpret=not on_tpu(), **kw)
 
 
 def fused_precond(a_inv: jax.Array, g: jax.Array, g_inv: jax.Array,
                   **kw):
-    kw.setdefault("interpret", not on_tpu())
-    return _fused_precond(a_inv, g, g_inv, **kw)
+    return _fused_precond(a_inv, g, g_inv, interpret=not on_tpu(), **kw)
 
 
 def smw_update(inv: jax.Array, v: jax.Array, *, decay: float,
                cscale: float, **kw) -> jax.Array:
-    kw.setdefault("interpret", not on_tpu())
-    return _smw_update(inv, v, decay=decay, cscale=cscale, **kw)
+    return _smw_update(inv, v, decay=decay, cscale=cscale,
+                       interpret=not on_tpu(), **kw)

@@ -39,9 +39,8 @@ def neumann_inv_ref(a: jax.Array, damping: jax.Array, *,
         n = a1.shape[-1]
         eye = jnp.eye(n, dtype=jnp.float32)
         ad = a1.astype(jnp.float32) + lam * eye
-        a_hi16 = ad.astype(jnp.bfloat16)
+        a_hi16, a_lo16 = split_hi_lo_bf16(ad)
         a_hi = a_hi16.astype(jnp.float32)
-        a_lo16 = (ad - a_hi).astype(jnp.bfloat16)
         x = a_hi / _norm_bound_hi(a_hi)
 
         def ns(_, x):
@@ -109,7 +108,8 @@ def smw_update_ref(inv: jax.Array, v: jax.Array, *, decay: float,
     """Oracle for kernels.smw_update: the identical padded two-pass
     pipeline — per-block hi/lo partial products in the same order the
     interpreted grid executes them, and the *same* batched k x k solve
-    expression between passes — so the kernel must match bitwise."""
+    expression between passes — so the kernel matches it to fp32
+    reassociation error."""
     n, k, bs = v.shape
     bs_p = max(128, (-(-bs // 128)) * 128)
     k_p = max(128, (-(-k // 128)) * 128)

@@ -33,39 +33,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.quantize import hilo_matmul
+from repro.kernels.vmem import VMEM_LIMIT_BYTES
+
 __all__ = ["smw_update"]
-
-
-def _split(x):
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
-
-
-def _hilo_mm(a, b):
-    """bf16-operand fp32-accumulate matmul (three partial products)."""
-    a_hi, a_lo = _split(a)
-    b_hi, b_lo = _split(b)
-
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32)
-
-    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
 
 
 def _kernel_stats(inv_ref, v_ref, m_ref, y_ref, s_ref, *, inv_decay):
     inv = inv_ref[0]
     m = (inv + inv.T) * inv_decay
     v = v_ref[0]
-    y = _hilo_mm(v, m)                 # VMM 1: (k, bs) stays in VMEM
+    y = hilo_matmul(v, m)                 # VMM 1: (k, bs) stays in VMEM
     m_ref[0] = m
     y_ref[0] = y
-    s_ref[0] = _hilo_mm(y, v.T)        # capacitance, k x k
+    s_ref[0] = hilo_matmul(y, v.T)        # capacitance, k x k
 
 
 def _kernel_apply(m_ref, y_ref, z_ref, o_ref):
     # outer-product correction: VMM 2, intermediates never left VMEM
-    o_ref[0] = m_ref[0] - _hilo_mm(y_ref[0].T, z_ref[0])
+    o_ref[0] = m_ref[0] - hilo_matmul(y_ref[0].T, z_ref[0])
 
 
 def _pad2(x, r, c):
@@ -120,6 +106,7 @@ def smw_update(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(inv_p, v_p)
@@ -139,6 +126,7 @@ def smw_update(
         out_shape=jax.ShapeDtypeStruct((n, bs_p, bs_p), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(m, y, z)

@@ -1,12 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract roofline terms — no allocation, ever.
 
-The two lines above MUST precede any jax-touching import: jax locks the
-device count at first backend init, and the dry-run needs 512 host
-placeholder devices to build the (2, 16, 16) production mesh. Smoke
+The lines above MUST precede any jax-touching import: jax locks the
+platform and device count at first backend init, and the dry-run needs
+512 host placeholder devices (on a TPU host it would otherwise see the
+local chips) to build the (2, 16, 16) production mesh. Smoke
 tests and benchmarks never import this module, so they see 1 device.
 
 Usage:

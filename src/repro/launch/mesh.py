@@ -22,6 +22,15 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code shards
+    through ``shard_hint`` constraints and leaves propagation to GSPMD,
+    which ``make_mesh``'s default ``Explicit`` axes would refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False, pp: int = 1,
                          model: int = 16):
     """The full (pod, stage, data, model) layout on 256/512 chips.
@@ -42,8 +51,7 @@ def make_production_mesh(*, multi_pod: bool = False, pp: int = 1,
                 f"pp={pp} does not divide the data axis ({d})")
         shape = shape[:-2] + (pp, d // pp, shape[-1])
         axes = axes[:-2] + ("stage", "data", "model")
-    auto = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, axis_types=auto)
+    return _auto_mesh(shape, axes)
 
 
 def make_dev_mesh(model: int = 1):
@@ -52,7 +60,7 @@ def make_dev_mesh(model: int = 1):
     n = jax.device_count()
     if n % model:
         raise ValueError(f"{n} devices not divisible by model={model}")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_pipeline_mesh(pp: int, model: int = 1):
@@ -68,5 +76,5 @@ def make_pipeline_mesh(pp: int, model: int = 1):
     if n % (pp * model):
         raise ValueError(
             f"{n} devices not divisible by pp={pp} * model={model}")
-    return jax.make_mesh((pp, n // (pp * model), model),
-                         ("stage", "data", "model"))
+    return _auto_mesh((pp, n // (pp * model), model),
+                      ("stage", "data", "model"))

@@ -15,8 +15,10 @@ puts on the wire; all-gather output counts its *input* operands times
 (group-1)/group under ring scheduling — we report raw operand bytes as
 the spec'd metric and keep scheduling factors out).
 
-Hardware constants: TPU v5e-class — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (assignment-provided).
+Hardware constants: one TPU v5e chip — 197 TFLOP/s bf16, 819 GB/s
+HBM, ~50 GB/s/link ICI. They describe the v5e that the dry run models
+on CPU stand-in devices, and nothing else: :func:`analyze` refuses a
+real device of another kind (:func:`check_device`).
 """
 
 from __future__ import annotations
@@ -24,6 +26,8 @@ from __future__ import annotations
 import dataclasses
 import re
 from typing import Dict, Optional
+
+import jax
 
 PEAK_FLOPS = 197e12          # bf16 / chip
 HBM_BW = 819e9               # bytes/s / chip
@@ -148,6 +152,18 @@ class Roofline:
         }
 
 
+def check_device(device) -> None:
+    """Raise unless the peaks above describe ``device``: a CPU stand-in
+    of the dry run, or a TPU v5e (``device_kind`` "TPU v5 lite")."""
+    if device.platform == "cpu":
+        return
+    kind = device.device_kind.lower()
+    if "v5 lite" not in kind and "v5e" not in kind:
+        raise ValueError(
+            f"roofline peaks are the TPU v5e's; refusing device kind "
+            f"{device.device_kind!r}")
+
+
 def analyze(lowered, compiled, chips: int) -> Roofline:
     """Roofline terms from the compiled per-device module.
 
@@ -157,6 +173,7 @@ def analyze(lowered, compiled, chips: int) -> Roofline:
     """
     from repro.launch import hlo_analysis
 
+    check_device(jax.devices()[0])
     cost = compiled.cost_analysis()
     if isinstance(cost, (list, tuple)):
         cost = cost[0]

@@ -1,15 +1,18 @@
 """Serving driver: continuous-batching engine (default) or the legacy
 single-static-batch path (``--static``).
 
-CPU/container quickstart (reduced config, real tokens):
+CPU quickstart (reduced config, real tokens; drop ``--smoke`` and
+``JAX_PLATFORMS=cpu`` on a TPU host for the published widths):
 
   # continuous batching over a synthetic mixed-length request trace
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
-      --smoke --requests 6 --max-slots 2 --prompt-len 24 --gen 8
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+      --arch qwen2-0.5b --smoke --requests 6 --max-slots 2 \
+      --prompt-len 24 --gen 8
 
   # legacy fixed-batch prefill+decode (baseline / A-B reference)
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
-      --smoke --static --batch 4 --prompt-len 32 --gen 16
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+      --arch qwen2-0.5b --smoke --static --batch 4 --prompt-len 32 \
+      --gen 16
 
 Both paths sample on device (greedy by default; ``--no-greedy`` enables
 ``--temperature``/``--top-k`` sampling) and warm up the jitted programs
@@ -34,6 +37,7 @@ from repro import obs as obs_mod
 from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticTokens
 from repro.dist import sharding as shard_rules
+from repro.launch import compile_cache
 from repro.launch import steps as steps_mod
 from repro.launch.mesh import make_dev_mesh
 from repro.serve import (
@@ -312,6 +316,7 @@ def serve_static(cfg, args, mesh):
 
 
 def main(argv=None):
+    compile_cache.enable()
     args = build_parser().parse_args(argv)
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     mesh = make_dev_mesh(args.model_parallel)

@@ -1,14 +1,19 @@
 """End-to-end training driver: K-FAC (or SGD baseline) + fault-tolerant
 loop + checkpointing + synthetic data, on whatever devices exist.
 
-CPU/container quickstart (reduced config, real steps):
-  PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
-      --smoke --steps 40 --batch 8 --seq 64 --ckpt-dir /tmp/ck
+CPU quickstart (reduced config, real steps):
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+      --arch qwen1.5-0.5b --smoke --steps 40 --batch 8 --seq 64 \
+      --ckpt-dir /tmp/ck
 
-Pod posture: the same driver on a TPU slice with ``--full
---model-parallel 16``; the mesh comes from ``runtime.elastic`` so a
-shrunk device pool after a failure re-forms automatically (drill it
-with ``--inject-failure-at N``).
+On a TPU host, drop ``--smoke`` for the published widths (one v5e
+holds qwen2-0.5b: ``--batch 8 --seq 1024 --lr 3e-3``; the default
+``--lr 3e-2`` overshoots at that width, the loss rising from 12.1 to
+17.7 by the third step) and add
+``--model-parallel N`` / ``--pp N`` / ``--dist-inv`` on a slice; the
+mesh comes from ``runtime.elastic`` so a shrunk device pool after a
+failure re-forms automatically (drill it with ``--inject-failure-at
+N``). ``--ckpt-dir`` is restored from when it holds a checkpoint.
 
 The K-FAC cadence follows the paper (Fig. 8): FP/BP/WU every step; the
 SU graph (factor stats) every ``--stats-every`` steps on a subsampled
@@ -37,6 +42,7 @@ from repro.core.kfac import KFACConfig
 from repro.data import SyntheticTokens
 from repro.dist import sharding as shard_rules
 from repro.dist.api import mesh_ndev
+from repro.launch import compile_cache
 from repro.launch import steps as steps_mod
 from repro.launch.steps import TrainState
 from repro.runtime import DeviceLoss, LoopConfig, TrainLoop, elastic_mesh
@@ -344,7 +350,12 @@ class SGDProgram:
         return lambda key: lookup.get(key)
 
 
-def main(argv=None):
+def main(argv=None, devices=None):
+    """Parse ``argv``, train, print and return the loop summary.
+
+    ``devices``: the device pool to mesh over (default: every device of
+    the process, ``jax.devices()``)."""
+    compile_cache.enable()
     logging.basicConfig(level=logging.INFO)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -422,11 +433,15 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
+    # the model's in-scan activation Grams and the K-FAC factor state
+    # must block features alike
+    cfg = dataclasses.replace(
+        cfg, soi_block=min(args.block_size, cfg.soi_block))
     obs = obs_mod.from_args(args)
     kcfg = KFACConfig(
         lr=args.lr, damping=args.damping,
         stats_every=args.stats_every, inv_every=args.inv_every,
-        block_size=min(args.block_size, cfg.soi_block),
+        block_size=cfg.soi_block,
         stats_batch=args.batch, stats_seq=args.seq,
         precision=args.precision)
 
@@ -457,12 +472,16 @@ def main(argv=None):
             fired.append(step)
             raise DeviceLoss(0, "injected failure drill")
 
+    mesh_fn = None if devices is None else (
+        lambda exclude=0: elastic_mesh(args.model_parallel, pp=args.pp,
+                                       devices=devices, exclude=exclude,
+                                       obs=obs))
     loop = TrainLoop(
         LoopConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                    ckpt_every=args.ckpt_every,
                    model_parallel=args.model_parallel,
                    pipeline_parallel=args.pp),
-        program, ds,
+        program, ds, mesh_fn=mesh_fn,
         inject=inject if args.inject_failure_at >= 0 else None,
         obs=obs)
     summary = loop.run()

@@ -92,4 +92,5 @@ def elastic_mesh(
                     "mesh (re-)formations, recoveries included").inc()
         obs.event("remesh", shape=dict(zip(names, shape)),
                   n_devices=n, excluded=exclude)
-    return Mesh(arr, names)
+    return Mesh(arr, names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(names))

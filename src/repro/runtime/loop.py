@@ -98,6 +98,8 @@ class TrainLoop:
             hard_deadline_s=cfg.hard_deadline_s,
             obs=self.obs)
         self.metrics_history: list = []
+        # fenced wall of every completed step, replays included
+        self.step_walls: list = []
         self.n_recoveries = 0
         self._mesh_cm = None
         # device metrics buffered per step, drained in one batched
@@ -162,6 +164,15 @@ class TrainLoop:
     # -- main --------------------------------------------------------------
 
     def run(self) -> Dict[str, Any]:
+        try:
+            return self._run()
+        finally:
+            # leave no mesh in scope, also when a step error propagates
+            if self._mesh_cm is not None:
+                self._mesh_cm.__exit__(None, None, None)
+                self._mesh_cm = None
+
+    def _run(self) -> Dict[str, Any]:
         failures = 0
         exclude = 0
         mesh, state, cursor, step_fn = self._start()
@@ -223,6 +234,7 @@ class TrainLoop:
                 continue
 
             failures = 0
+            self.step_walls.append(self.watchdog.last_dt)
             cursor = cursor.advance()
             if self.obs.enabled:
                 self._c_steps.inc()
@@ -262,41 +274,22 @@ class TrainLoop:
 
         self._drain_taps()   # tail of the last (partial) window
         self.ckpt.wait()
-        if self._mesh_cm is not None:
-            self._mesh_cm.__exit__(None, None, None)
-            self._mesh_cm = None
         return {
             "steps": cursor.step,
             "wall_s": time.monotonic() - t_start,
             "recoveries": self.n_recoveries,
             "stragglers": self.watchdog.n_stragglers,
+            "step_wall_s": self.step_walls,
             "history": self.metrics_history,
         }
 
 
 #: XLA runtime status markers that indicate a sick device / lost data
 #: rather than a programming error (absl status codes as surfaced in
-#: XlaRuntimeError messages, plus the legacy CamelCase spellings).
+#: ``JaxRuntimeError`` messages).
 _XLA_RECOVERABLE_MARKERS = (
-    "RESOURCE_EXHAUSTED", "ResourceExhausted",
-    "DATA_LOSS", "DataLoss",
-    "UNAVAILABLE", "Unavailable",
-    "ABORTED", "Aborted",
+    "RESOURCE_EXHAUSTED", "DATA_LOSS", "UNAVAILABLE", "ABORTED",
 )
-
-
-def _xla_runtime_error_types():
-    """The XLA runtime exception class(es) for this jax version."""
-    types = []
-    err = getattr(jax, "errors", None)
-    if err is not None and hasattr(err, "JaxRuntimeError"):
-        types.append(err.JaxRuntimeError)
-    try:
-        from jax._src.lib import xla_client
-        types.append(xla_client.XlaRuntimeError)
-    except Exception:  # pragma: no cover - very old/new jax
-        pass
-    return tuple(types)
 
 
 def _recoverable(e: BaseException) -> bool:
@@ -311,7 +304,7 @@ def _recoverable(e: BaseException) -> bool:
 
     if isinstance(e, (DeviceLoss, StepDeadlineExceeded)):
         return True
-    if not isinstance(e, _xla_runtime_error_types()):
+    if not isinstance(e, jax.errors.JaxRuntimeError):
         return False
     msg = str(e)
     return any(m in msg for m in _XLA_RECOVERABLE_MARKERS)

@@ -8,9 +8,9 @@ watchdog keeps a rolling median of healthy step times and
   ``straggler_factor x median`` (logged; counted; the train loop may
   respond by re-balancing or excluding the slow pod),
 * raises :class:`StepDeadlineExceeded` from a daemon timer when a step
-  exceeds ``hang_factor x median`` (or ``hard_deadline_s``), which the
-  retrying loop treats like a device failure: checkpoint-restore +
-  re-mesh (``runtime/loop.py``).
+  exceeds ``max(hang_factor x median, MIN_HANG_S)`` (or
+  ``hard_deadline_s``), which the retrying loop treats like a device
+  failure: checkpoint-restore + re-mesh (``runtime/loop.py``).
 
 Used as a context manager around each step::
 
@@ -25,6 +25,12 @@ import statistics
 import threading
 import time
 from typing import Any, List, Optional
+
+
+#: Floor of the median-based hang deadline, seconds: it keeps
+#: millisecond steps from reading host noise (a GC pause, a busy core, a
+#: checkpoint thread) as a hang.
+MIN_HANG_S = 1.0
 
 
 class StepDeadlineExceeded(RuntimeError):
@@ -50,6 +56,7 @@ class StepWatchdog:
         self.n_steps = 0
         self.n_stragglers = 0
         self.last_was_straggler = False
+        self.last_dt: Optional[float] = None
         # observability taps (repro.obs): step-wall histogram +
         # straggler counter; handles held once, observed per step
         self._h_wall = self._c_straggler = None
@@ -79,7 +86,7 @@ class StepWatchdog:
         med = self.median()
         cands = []
         if med is not None:
-            cands.append(self.hang_factor * med)
+            cands.append(max(self.hang_factor * med, MIN_HANG_S))
         if self.hard_deadline_s is not None:
             cands.append(self.hard_deadline_s)
         return min(cands) if cands else None
@@ -105,6 +112,7 @@ class StepWatchdog:
             if timer is not None:
                 timer.cancel()
         dt = time.monotonic() - t0
+        self.last_dt = dt
         self.n_steps += 1
         if self._h_wall is not None:
             self._h_wall.observe(dt)

@@ -2,9 +2,7 @@
 
 1. Make ``repro`` importable from the src/ layout even without
    ``PYTHONPATH=src`` or an editable install.
-2. Import :mod:`repro.compat` so the jax API backfills (set_mesh,
-   AxisType, shard_map, ...) are installed before any test touches jax.
-3. If the real ``hypothesis`` package is unavailable in the container,
+2. If the real ``hypothesis`` package is unavailable in the container,
    register a minimal deterministic stand-in that supports the subset
    used by this suite (``given``/``settings`` and the ``integers`` /
    ``floats`` / ``sampled_from`` / ``booleans`` strategies). It runs
@@ -13,7 +11,7 @@
    a dependency the image doesn't bake in. CI installs the real
    package (``pip install -e ".[test]"``), so there the stub is dormant;
    ``tests/test_hypothesis_stub.py`` keeps both code paths green.
-4. Provide the ``multidevice`` marker + subprocess runner for tests
+3. Provide the ``multidevice`` marker + subprocess runner for tests
    that need a forced multi-device host platform
    (``XLA_FLAGS=--xla_force_host_platform_device_count=4``). jax fixes
    its device count at backend init, so those tests only run when the
@@ -41,8 +39,6 @@ try:
     import repro  # noqa: F401
 except ImportError:
     sys.path.insert(0, _SRC)
-
-import repro.compat  # noqa: E402,F401  (installs jax backfills)
 
 
 def make_hypothesis_stub():

@@ -502,7 +502,8 @@ def test_tap_drain_multidevice_parity():
     """Tapped metrics produced by a sharded program drain to the same
     host floats a per-metric blocking readback would give, and the
     tapped step's (sharded) state is bitwise the untapped one."""
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("data"))
 

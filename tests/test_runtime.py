@@ -142,6 +142,9 @@ def test_loop_gives_up_after_max_failures(tmp_path):
 
     with pytest.raises(DeviceLoss):
         _run(tmp_path, inject=inject)
+    # the loop's mesh does not outlive the failed run
+    from repro.dist.api import active_mesh
+    assert active_mesh() is None
 
 
 # ---------------------------------------------------------------------------
@@ -151,11 +154,7 @@ def test_loop_gives_up_after_max_failures(tmp_path):
 def test_recoverable_classification_table():
     from repro.runtime.loop import _recoverable
 
-    try:
-        from jax._src.lib import xla_client
-        XlaErr = xla_client.XlaRuntimeError
-    except Exception:
-        XlaErr = None
+    XlaErr = jax.errors.JaxRuntimeError
 
     # the repo's own fault types restore
     assert _recoverable(DeviceLoss(0, "drill"))
@@ -169,13 +168,12 @@ def test_recoverable_classification_table():
     assert not _recoverable(KeyError("layers/0/attn"))
     # sick-device markers only count on XLA runtime errors
     assert not _recoverable(RuntimeError("RESOURCE_EXHAUSTED: fake"))
-    if XlaErr is not None:
-        assert _recoverable(XlaErr(
-            "RESOURCE_EXHAUSTED: out of memory allocating 1g"))
-        assert _recoverable(XlaErr("DATA_LOSS: checkpoint shard lost"))
-        assert _recoverable(XlaErr("UNAVAILABLE: slice health check"))
-        assert not _recoverable(XlaErr(
-            "INVALID_ARGUMENT: mismatched shapes"))
+    assert _recoverable(XlaErr(
+        "RESOURCE_EXHAUSTED: out of memory allocating 1g"))
+    assert _recoverable(XlaErr("DATA_LOSS: checkpoint shard lost"))
+    assert _recoverable(XlaErr("UNAVAILABLE: slice health check"))
+    assert not _recoverable(XlaErr(
+        "INVALID_ARGUMENT: mismatched shapes"))
 
 
 def test_loop_raises_on_programming_error(tmp_path):
