@@ -559,6 +559,7 @@ def _apply_updates_pooled(leaves_p, treedef, leaves_pre, leaves_g,
     return params2, state2
 
 
+@jax.named_scope("wu")
 def apply_updates(params: Any, grads: Any, state: KFACState,
                   specs: Mapping[str, LinearSpec],
                   cfg: KFACConfig, wu_plan=None,

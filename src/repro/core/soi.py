@@ -105,6 +105,7 @@ def pad_to_blocks(x: jax.Array, axis: int, bs: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+@jax.named_scope("soi_gram")
 def blocked_gram(a: jax.Array, cap: int) -> jax.Array:
     """Diagonal-block Gram of activations.
 
@@ -141,6 +142,7 @@ def blocked_tokens(a: jax.Array, cap: int) -> jax.Array:
     return a.reshape(a.shape[:-1] + (nb, bs))
 
 
+@jax.named_scope("soi_gram")
 def gram_from_tokens(bt: jax.Array) -> jax.Array:
     """(..., T, nb, bs) blocked tokens -> (..., nb, bs, bs) Gram.
 

@@ -395,6 +395,7 @@ def make_inv_refresh(cfg: ModelConfig, kcfg: KFACConfig, *,
         plan = make_plan(ab.kfac.factors, mesh_ndev(mesh), kcfg,
                          pdiv_cap_bs=pdiv_cap_bs)
 
+    @jax.named_scope("inv")
     def refresh(factors):
         return invert_factor_tree(factors, kcfg, mesh=mesh, plan=plan)
 

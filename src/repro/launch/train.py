@@ -28,7 +28,6 @@ import contextlib
 import dataclasses
 import json
 import logging
-import time
 from functools import partial
 from typing import Any
 
@@ -66,19 +65,15 @@ def _sharding_lookup(tree) -> dict:
     return {_key_of_path(p): s for p, s in leaves}
 
 
-@contextlib.contextmanager
-def _phase(obs, hist, name):
-    """Phase span + dispatch-wall histogram sample. Dispatch-timed on
-    purpose: fencing each phase would serialize exactly the async
-    overlap (inv refresh, pipelined microbatches) the phases exist to
-    exploit; the loop's own step fence gives the honest total."""
-    if hist is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    with obs.tracer.span(f"phase:{name}", cat="dispatch"):
-        yield
-    hist.observe(time.perf_counter() - t0, phase=name)
+def _phase(obs, name, cat="dispatch"):
+    """``phase:<name>`` span, a no-op unless ``obs`` is enabled.
+    Dispatch-timed on purpose: fencing each phase would serialize
+    exactly the async overlap (inv refresh, pipelined microbatches) the
+    phases exist to exploit; the loop's own step fence gives the honest
+    total. ``phase:sync`` brackets the host's one wait on the device."""
+    if not obs.enabled:
+        return contextlib.nullcontext()
+    return obs.tracer.span(f"phase:{name}", cat=cat)
 
 
 @dataclasses.dataclass
@@ -123,6 +118,7 @@ class KFACProgram:
     obs: Any = None
 
     def __post_init__(self):
+        self.programs = {}
         self._refresher = None
         self._smw = None
         self._sched = None
@@ -226,15 +222,14 @@ class KFACProgram:
                                      obs=self.obs)
         else:
             self._smw = None
+        #: the jitted programs step_fn dispatches, by name
+        self.programs = {"train": train, "stats": stats,
+                         "inv": refresh_into}
         refresher = self._refresher
         smw_ref = self._smw
         kcfg = self.kcfg
         sched = self._sched
         obs = self.obs
-        phase_h = obs.histogram(
-            "train_phase_s",
-            "per-phase dispatch wall (stats/inv/smw/train)") \
-            if obs.enabled else None
 
         def subsample(batch):
             sb = min(batch["tokens"].shape[0], kcfg.stats_batch)
@@ -252,21 +247,22 @@ class KFACProgram:
                 # incremental SOI: one fused rank-k program every step
                 # (stats + EMA + SMW inverse update + drift probe), the
                 # host gate falls back to refresh_into on drift
-                with _phase(obs, phase_h, "smw"):
+                with _phase(obs, "smw"):
                     state, metrics = smw_ref.step(state,
                                                   subsample(batch))
-                with _phase(obs, phase_h, "train"):
+                with _phase(obs, "train"):
                     state, m = train(state, batch)
                 metrics.update(m)
                 return state, metrics
-            i = int(jax.device_get(state.kfac.step))
+            with _phase(obs, "sync", cat="sync"):
+                i = int(jax.device_get(state.kfac.step))
             metrics = {}
             if i % kcfg.stats_every == 0:
-                with _phase(obs, phase_h, "stats"):
+                with _phase(obs, "stats"):
                     state, m = stats(state, subsample(batch))
                 metrics.update(m)
             if i % kcfg.inv_every == 0:
-                with _phase(obs, phase_h, "inv"):
+                with _phase(obs, "inv"):
                     if refresher is not None and sched is not None:
                         # pipelined: dispatch the refresh just before
                         # the pipeline program so INV overlaps its
@@ -285,7 +281,7 @@ class KFACProgram:
                         state = state._replace(kfac=kst._replace(
                             inverses=refresh_into(kst.factors,
                                                   kst.inverses)))
-            with _phase(obs, phase_h, "train"):
+            with _phase(obs, "train"):
                 state, m = train(state, batch)
             metrics.update(m)
             return state, metrics

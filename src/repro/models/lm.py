@@ -167,6 +167,7 @@ def init(cfg, key) -> Dict:
 # Blocks
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn")
 def _attn_block(cfg, p, x, positions, ctx, prefix, *, window=0,
                 cache=None, idx=None, mrope=False, table=None):
     """Pre-norm attention sub-layer. cache: dict(k, v, pos) slices for
@@ -258,6 +259,7 @@ def _attn_block(cfg, p, x, positions, ctx, prefix, *, window=0,
     return x + shard_acts(out), new_cache
 
 
+@jax.named_scope("mlp")
 def _mlp_block(cfg, p, x, ctx, prefix):
     xin = rms_norm(x, p["ln2"], cfg.norm_eps)
     if p["mlp"]["wg"].shape[-1] < cfg.d_ff:
@@ -334,6 +336,7 @@ def _embed(cfg, params, batch, positions):
     return shard_acts(x)
 
 
+@jax.named_scope("head")
 def _logits(cfg, params, x):
     dt = x.dtype
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -485,6 +488,7 @@ def forward(cfg, params, batch, taps=None, collect=False, cache=None,
     return logits, stats, new_cache
 
 
+@jax.named_scope("head")
 def loss_from_logits(cfg, logits, batch):
     """Next-token cross-entropy from full-sequence logits — the tail of
     :func:`loss_fn`, shared with the pipeline's last stage

@@ -164,6 +164,7 @@ def _use_fast_path(cfg, ctx, prefix) -> bool:
     return nt_loc_ok
 
 
+@jax.named_scope("moe")
 def moe_ffn(cfg, p: Dict, x: jax.Array, ctx: Optional[Ctx],
             prefix: str) -> jax.Array:
     """x: (B, T, D) -> (B, T, D). Top-k routing with capacity + drop."""

@@ -52,6 +52,7 @@ def _ssm_params(cfg, p, xc, prefix, ctx):
     return dt, b.astype(jnp.float32), c.astype(jnp.float32)
 
 
+@jax.named_scope("ssm")
 def mamba_mixer(cfg, p: Dict, x: jax.Array, ctx: Optional[Ctx],
                 prefix: str,
                 state: Optional[Tuple[jax.Array, jax.Array]] = None,

@@ -101,6 +101,7 @@ def init(cfg, key) -> Dict:
     }
 
 
+@jax.named_scope("attn")
 def _mha(cfg, p, xq, xkv, ctx, prefix, causal, q_pos, kv_pos,
          cache=None, idx=None, shared_kv=None):
     """One attention with optional cache / precomputed kv."""
@@ -131,6 +132,7 @@ def _mha(cfg, p, xq, xkv, ctx, prefix, causal, q_pos, kv_pos,
     return out, new_cache
 
 
+@jax.named_scope("mlp")
 def _mlp(cfg, p, x, ctx, prefix):
     hidden = gelu(dense(x, p["w1"], f"{prefix}/w1", ctx, bias=p["b1"]))
     hidden = shard_hint(hidden, BATCH_AXES, None, MODEL)
@@ -178,6 +180,7 @@ def _mha_kv(cfg, p, xkv, ctx, prefix):
     return k.reshape(B, -1, h, hd), v.reshape(B, -1, h, hd)
 
 
+@jax.named_scope("head")
 def _head_logits(cfg, params, x):
     """Tied vocab head on post-``dec_ln_f`` activations.
 
@@ -200,6 +203,7 @@ def _head_logits(cfg, params, x):
     return shard_hint(logits, BATCH_AXES, None, MODEL)
 
 
+@jax.named_scope("head")
 def loss_from_logits(cfg, logits, batch):
     """Teacher-forced CE over decoder tokens — the tail shared by the
     monolithic :func:`loss_fn` and the pipeline's last stage."""

@@ -24,8 +24,6 @@ both see one stable identity per label set::
     toks.inc(8)
     lat = reg.histogram("serve_ttft_s", help="submit -> first token")
     lat.observe(0.012)
-    phase = reg.histogram("train_phase_s", help="per-phase wall")
-    phase.observe(0.5, phase="wu")
 """
 
 from __future__ import annotations

@@ -208,7 +208,11 @@ class SMWRefresher:
         state, metrics = self.smw_step(state, batch)
         fallback = self.n_steps == 0
         if self._drift is not None:
-            d = float(self._drift)       # blocks on *last* step only
+            # the host gate: the one wait on the device in an SMW step
+            span = self._obs.span("phase:sync", cat="sync") \
+                if self._g_drift is not None else _null_cm()
+            with span:
+                d = float(self._drift)   # blocks on *last* step only
             self.last_drift = d
             if self._g_drift is not None:
                 self._g_drift.set(d)

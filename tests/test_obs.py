@@ -453,8 +453,7 @@ def test_train_cli_obs_smoke(tmp_path):
     assert summary["steps"] == 4
     assert len(summary["history"]) == 4      # every step recorded
     names = _prom_names(obs_dir / "metrics.prom")
-    need = {"train_steps_total", "train_step_wall_s", "train_phase_s",
-            "train_loss", "solve_smw_drift", "solve_smw_fallback_total",
+    need = {"train_steps_total", "train_step_wall_s", "train_loss", "solve_smw_drift", "solve_smw_fallback_total",
             "runtime_remesh_total"}
     assert need <= names, f"missing {need - names}"
     doc = json.load(open(obs_dir / "trace.json"))
