@@ -12,6 +12,9 @@ mapping argument):
                     trust-region dot accumulated in the same pass —
                     the fused VMM⊕INV crossbar-group image (Sec. V)
 
+Beside them, ``flash_attention`` runs the model's causal self-attention
+by blocked online softmax on a TPU (forward, dq and dkv kernels).
+
 Validated in interpret mode on CPU against ``ref.py`` oracles
 (tests/test_kernels.py sweeps shapes/dtypes).
 """

@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import soi
-from repro.dist.api import BATCH_AXES, DATA, MODEL, shard_hint
+from repro.dist.api import BATCH_AXES, DATA, MODEL, active_mesh, shard_hint
+from repro.kernels import flash_attention
 
 #: far-future sentinel position: the causal mask (q_pos >= kv_pos)
 #: excludes cache columns carrying it. Lives here (the lowest layer that
@@ -136,6 +137,11 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
     ``x``: (B, T, H, hd); ``positions``: (B, T) or (3, B, T) for M-RoPE
     (qwen2-vl), in which case ``sections`` gives the per-stream split of
     the hd/2 frequency channels (e.g. (16, 24, 24) for hd=128).
+
+    The halves are swapped by a product with a signed permutation
+    (:func:`_rotate_half`), which gives the values of splitting into
+    half-width slices; on a TPU the slices' (hd/2)-wide minor dim is
+    copied between layouts on its way to attention's head-major kernel.
     """
     hd = x.shape[-1]
     freqs = rope_freqs(hd, theta)            # (hd/2,)
@@ -155,10 +161,20 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
         ang = positions[..., None].astype(jnp.float32) * freqs
     sin = jnp.sin(ang)[:, :, None, :]
     cos = jnp.cos(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
+    xf = x.astype(jnp.float32)
+    out = (xf * jnp.concatenate([cos, cos], axis=-1)
+           + _rotate_half(xf) * jnp.concatenate([sin, sin], axis=-1))
     return out.astype(x.dtype)
+
+
+def _rotate_half(x: jax.Array) -> jax.Array:
+    """(x1, x2) -> (-x2, x1) along the last axis, as the product with a
+    signed permutation: exact at ``HIGHEST`` precision."""
+    h = x.shape[-1] // 2
+    eye, zero = jnp.eye(h, dtype=x.dtype), jnp.zeros((h, h), x.dtype)
+    r = jnp.block([[zero, eye], [-eye, zero]])
+    return jnp.einsum("...d,de->...e", x, r,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -182,16 +198,40 @@ def _gqa_scores_to_out(q, k, v, mask, dt):
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               q_pos: jax.Array, kv_pos: jax.Array,
               causal: bool = True, window: int = 0,
-              chunk: int = 0) -> jax.Array:
+              chunk: int = 0, implicit_positions: bool = False
+              ) -> jax.Array:
     """GQA attention with optional causality, sliding window, and
-    query-chunking (online softmax over KV chunks would be the Pallas
-    flash path; the XLA path chunks queries which bounds the score
-    materialization at (chunk x S)).
+    query-chunking.
 
     q: (B, T, H, hd); k/v: (B, S, Hkv, hd);
     q_pos: (B, T) absolute positions; kv_pos: (B, S).
     Returns (B, T, H, hd).
+
+    Two paths. Causal self-attention over the implicit positions
+    ``arange(T)`` (``implicit_positions``: the caller's k/v are this
+    call's own tokens and it passed no positions of its own), with no
+    window, ``T == S`` a multiple of 128 and no mesh of several devices
+    in scope, lowers on a TPU to the blocked online-softmax (flash)
+    kernel of ``kernels/flash_attention``: each score tile stays in
+    VMEM, blocks above the diagonal are skipped, and the backward
+    recomputes the tiles from the saved logsumexp. Every other call, and
+    every call lowered for another platform, takes the XLA path below:
+    dense f32 scores, masked, softmaxed, cast to the value dtype for PV;
+    queries longer than ``chunk`` are scanned in chunks, which bounds the
+    score tensor at (chunk x S).
     """
+    mesh = active_mesh()
+    if (implicit_positions and causal and not window
+            and flash_attention.supports(q.shape[1], k.shape[1])
+            and (mesh is None or mesh.size == 1)):
+        return jax.lax.platform_dependent(
+            q, k, v, tpu=flash_attention.causal_flash_attention,
+            default=lambda q, k, v: _xla_attention(
+                q, k, v, q_pos, kv_pos, causal, window, chunk))
+    return _xla_attention(q, k, v, q_pos, kv_pos, causal, window, chunk)
+
+
+def _xla_attention(q, k, v, q_pos, kv_pos, causal, window, chunk):
     B, T, H, hd = q.shape
     S = k.shape[1]
     hkv = k.shape[2]

@@ -169,12 +169,16 @@ def init(cfg, key) -> Dict:
 
 @jax.named_scope("attn")
 def _attn_block(cfg, p, x, positions, ctx, prefix, *, window=0,
-                cache=None, idx=None, mrope=False, table=None):
+                cache=None, idx=None, mrope=False, table=None,
+                implicit_positions=False):
     """Pre-norm attention sub-layer. cache: dict(k, v, pos) slices for
     this layer or None. ``table`` (B, nbps) switches the cache to the
     block-paged layout (repro.serve.paged): k/v/pos leaves are
     (n_blocks, block_len, ...) pools indirected per row through the
-    table. Returns (x + attn_out, new_cache)."""
+    table. ``implicit_positions``: ``positions`` is the ``arange`` that
+    :func:`forward` builds, which lets attention without a cache take
+    the flash kernel (``layers.attention``). Returns (x + attn_out,
+    new_cache)."""
     B, T, D = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     xin = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -249,7 +253,8 @@ def _attn_block(cfg, p, x, positions, ctx, prefix, *, window=0,
         kv_pos = q_pos
     out = attention(q, k_all, v_all, q_pos, kv_pos, causal=True,
                     window=window,
-                    chunk=cfg.attn_chunk if T > cfg.attn_chunk else 0)
+                    chunk=cfg.attn_chunk if T > cfg.attn_chunk else 0,
+                    implicit_positions=implicit_positions)
     out = out.reshape(B, T, h_loc * hd)
     out = dense(out, p["attn"]["wo"], f"{prefix}/attn/wo", ctx)
     if h_loc < h:
@@ -277,7 +282,8 @@ def _mlp_block(cfg, p, x, ctx, prefix):
 
 
 def _layer_apply(cfg, kind, p, x, positions, ctx, prefix, cache=None,
-                 idx=None, table=None, state_len=None):
+                 idx=None, table=None, state_len=None,
+                 implicit_positions=False):
     """One decoder layer of the given kind. Returns (x, new_cache).
 
     ``state_len`` (B,) is the per-row valid prefix of a right-padded
@@ -288,12 +294,14 @@ def _layer_apply(cfg, kind, p, x, positions, ctx, prefix, cache=None,
         window = cfg.window if kind == "local" else 0
         x, nc = _attn_block(cfg, p, x, positions, ctx, prefix,
                             window=window, cache=cache, idx=idx,
-                            mrope=(cfg.family == "vlm"), table=table)
+                            mrope=(cfg.family == "vlm"), table=table,
+                            implicit_positions=implicit_positions)
         x = _mlp_block(cfg, p, x, ctx, prefix)
         return x, nc
     if kind == "moe":
         x, nc = _attn_block(cfg, p, x, positions, ctx, prefix,
-                            cache=cache, idx=idx, table=table)
+                            cache=cache, idx=idx, table=table,
+                            implicit_positions=implicit_positions)
         xin = rms_norm(x, p["ln2"], cfg.norm_eps)
         x = x + moe_mod.moe_ffn(cfg, p["moe"], xin, ctx, f"{prefix}/moe")
         return x, nc
@@ -358,7 +366,8 @@ def _logits(cfg, params, x):
 
 
 def _scan_layers(cfg, params, x, positions, taps, collect, cache, idx,
-                 train, table=None, state_len=None):
+                 train, table=None, state_len=None,
+                 implicit_positions=False):
     """Run all layers; returns (x, stats, new_cache)."""
     stats_out: Dict[str, jax.Array] = {}
 
@@ -370,9 +379,10 @@ def _scan_layers(cfg, params, x, positions, taps, collect, cache, idx,
             p_l, taps_l, cache_l = xs
             ctx = Ctx(taps=taps_l or None, collect=collect,
                       soi_block=cfg.soi_block)
-            xnew, ncache = _layer_apply(cfg, kind, p_l, xcur, positions,
-                                        ctx, prefix, cache=cache_l, idx=idx,
-                                        table=table, state_len=state_len)
+            xnew, ncache = _layer_apply(
+                cfg, kind, p_l, xcur, positions, ctx, prefix,
+                cache=cache_l, idx=idx, table=table, state_len=state_len,
+                implicit_positions=implicit_positions)
             if cache_l is None:
                 ncache = None     # train: don't stack states as ys
             return xnew, (ctx.stats, ncache)
@@ -401,10 +411,11 @@ def _scan_layers(cfg, params, x, positions, taps, collect, cache, idx,
                 ctx = Ctx(taps=taps_u or None, collect=collect,
                           soi_block=cfg.soi_block)
                 c_i = cache_u.get(f"sub{i}") if cache_u else None
-                xcur, nc = _layer_apply(cfg, kind, p_u[f"sub{i}"], xcur,
-                                        positions, ctx, f"units/sub{i}",
-                                        cache=c_i, idx=idx,
-                                        state_len=state_len)
+                xcur, nc = _layer_apply(
+                    cfg, kind, p_u[f"sub{i}"], xcur, positions, ctx,
+                    f"units/sub{i}", cache=c_i, idx=idx,
+                    state_len=state_len,
+                    implicit_positions=implicit_positions)
                 stats.update(ctx.stats)
                 if nc is not None:
                     ncaches[f"sub{i}"] = nc
@@ -424,7 +435,8 @@ def _scan_layers(cfg, params, x, positions, taps, collect, cache, idx,
             c_i = tail_caches.get(f"sub{i}") if tail_caches else None
             x, nc = _layer_apply(cfg, kind, params["tail"][f"sub{i}"], x,
                                  positions, ctx, f"tail/sub{i}",
-                                 cache=c_i, idx=idx, state_len=state_len)
+                                 cache=c_i, idx=idx, state_len=state_len,
+                                 implicit_positions=implicit_positions)
             stats_out.update(ctx.stats)
             if nc is not None:
                 ncache_tail[f"sub{i}"] = nc
@@ -473,7 +485,8 @@ def forward(cfg, params, batch, taps=None, collect=False, cache=None,
     x = _embed(cfg, params, batch, positions)
     x, stats, new_cache = _scan_layers(
         cfg, params, x, positions, taps, collect, cache, idx, train,
-        table=table, state_len=state_len)
+        table=table, state_len=state_len,
+        implicit_positions="positions" not in batch and cache is None)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if last_only:
         x = x[:, -1:]

@@ -11,20 +11,27 @@ process may load the TPU library at a time, and each test worker
 imports every test file.
 """
 
+import dataclasses
 import os
+import re
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_smoke_config
+from repro.core.kfac import KFACConfig
 from repro.core.quantize import split_hi_lo_bf16
 from repro.kernels.bitslice_mm import bitslice_mm
 from repro.kernels.fused_gram_solve import fused_gram_inv
 from repro.kernels.fused_precond import fused_precond
 from repro.kernels.neumann_inv import neumann_inv
 from repro.kernels.smw_update import smw_update
+from repro.launch import steps as steps_mod
+from repro.launch.train import KFACProgram
 
 
 @pytest.fixture(scope="module")
@@ -105,3 +112,73 @@ def test_kernel_splits_on_the_bits(kernel):
     fn, shapes = cases[kernel]
     args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
     assert "shift_right_logical" in str(jax.make_jaxpr(fn)(*args))
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_flash_attention_compiles_for_v5e(one_chip, hd):
+    """The flash kernel's forward, dq and dkv calls at 1024 tokens (one
+    1024 block), GQA 4 on 2, for head dim 64 and for 128, whose scale
+    is no power of two."""
+    from repro.kernels.flash_attention import causal_flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(causal_flash_attention(q, k, v).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((2, 1024, h, hd), jnp.bfloat16,
+                                 sharding=one_chip) for h in (4, 2, 2)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def _custom_call_stacks(text):
+    """The name stack (``op_name``) of each Mosaic custom call of a
+    compiled program; an instruction's text runs to the next one."""
+    stacks = []
+    for m in re.finditer(r"\n  %\S+ = (.*?)(?=\n  %|\n\n|\n}\n)", text,
+                         re.S):
+        if 'custom_call_target="tpu_custom_call"' in m.group(1):
+            name = re.search(r'op_name="([^"]*)"', m.group(1))
+            stacks.append(name.group(1) if name else "")
+    return stacks
+
+
+def _under(scope, stack):
+    return any(re.fullmatch(r"(?:\w+\()*%s\)*" % scope, c)
+               for c in stack.split("/")[1:])
+
+
+def test_flash_attention_is_charged_to_attn(one_chip):
+    """The K-FAC train and statistics programs, at smoke widths with head
+    dim 64 and 256 tokens, compiled for one v5e chip: attention lowers
+    to the flash kernel's Mosaic calls, and each carries the ``attn``
+    scope in its op metadata, on the forward, the recomputed forward and
+    the backward, so the benchmark charges their time to attention."""
+    cfg = dataclasses.replace(get_smoke_config("qwen2-0.5b"), head_dim=64,
+                              soi_block=32)
+    kcfg = KFACConfig(block_size=32, stats_every=2, inv_every=2,
+                      stats_batch=2, stats_seq=256, inv_method="exact")
+    prog = KFACProgram(cfg, kcfg)
+    mesh = jax.sharding.Mesh(
+        np.array(list(one_chip.device_set)).reshape(1, 1),
+        ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 256), jnp.int32)}
+    with jax.set_mesh(mesh):
+        prog.make_step(mesh)
+        ab = steps_mod.abstract_train_state(cfg, kcfg)
+        for name in ("train", "stats"):
+            text = prog.programs[name].lower(ab, batch).compile().as_text()
+            stacks = [s for s in _custom_call_stacks(text)
+                      if "/pallas_call" in s and "flash_attention" in s]
+            assert stacks, name
+            assert all(_under("attn", s) for s in stacks), stacks
+            passes = {"backward": [s for s in stacks
+                                   if "/transpose(" in s
+                                   and "/rematted_computation/" not in s],
+                      "recomputed": [s for s in stacks
+                                     if "/rematted_computation/" in s],
+                      "forward": [s for s in stacks
+                                  if "/transpose(" not in s]}
+            for p, found in passes.items():
+                assert found, (name, p, stacks)
