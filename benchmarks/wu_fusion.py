@@ -57,7 +57,7 @@ _HLO_SKIP = {"parameter", "constant", "get-tuple-element", "tuple",
              "bitcast"}
 
 ARCHS = ("qwen1.5-0.5b", "qwen2-0.5b")
-EXTRA_ARCHS = ("moonshot-v1-16b-a3b",)    # recorded, not asserted
+EXTRA_ARCHS = ("moonlight-16b-a3b",)    # recorded, not asserted
 BLOCK_SIZE = 16
 REPS = 51
 

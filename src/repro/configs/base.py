@@ -24,9 +24,25 @@ class ModelConfig:
     norm_eps: float = 1e-6
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0              # the router's width (all experts)
     top_k: int = 0
     capacity_factor: float = 1.25
+    # the dropless expert-share layer (DeepSeek-V3 style; models/moe.py
+    # ``share_ffn``): sorted grouped matmuls over the experts this
+    # device holds, no capacity and no drop
+    dropless: bool = False
+    n_shared_experts: int = 0       # one SwiGLU MLP of this many widths
+    d_expert: int = 0               # routed expert width (0: d_ff)
+    n_dense_layers: int = 0         # leading dense layers (width d_ff)
+    routed_scale: float = 1.0       # routed weights' scale after top-k
+    experts_held: int = 0           # experts held here (0: all)
+    expert_first: int = 0           # the first held expert's id
+
+    # --- latent attention (MLA; kv_lora_rank > 0), training form ---
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     # --- SSM (mamba1) ---
     ssm_state: int = 0
@@ -62,9 +78,53 @@ class ModelConfig:
     subquadratic: bool = False      # can run long_500k
     has_decoder: bool = True
 
+    def __post_init__(self):
+        if self.dropless and not (self.family == "moe" and self.mla):
+            raise ValueError(f"{self.name}: the dropless expert-share "
+                             f"layer is written for MoE layers with MLA")
+
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // max(self.n_heads, 1)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_dim(self) -> int:
+        """Query/key head width: nope + rope channels under MLA."""
+        return self.qk_nope_dim + self.qk_rope_dim if self.mla else self.hd
+
+    @property
+    def d_expert_(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    def _mla_params(self) -> int:
+        d, h, r = self.d_model, self.n_heads, self.kv_lora_rank
+        return (d * h * self.qk_dim + d * (r + self.qk_rope_dim)
+                + r * h * (self.qk_nope_dim + self.v_head_dim)
+                + h * self.v_head_dim * d)
+
+    def _dropless_counts(self, experts: float) -> float:
+        """Matmul weights of the dropless MLA + expert-share stack with
+        ``experts`` routed experts per MoE layer, the head included."""
+        d, v = self.d_model, self.vocab
+        n = v * d * (1 if self.tie_embeddings else 2)
+        attn = self._mla_params()
+        dense = attn + 3 * d * self.d_ff
+        moe = (attn + d * self.n_experts
+               + 3 * d * self.n_shared_experts * self.d_expert_
+               + experts * 3 * d * self.d_expert_)
+        return n + self.n_dense_layers * dense + self.n_moe_layers * moe
 
     @property
     def d_inner(self) -> int:
@@ -83,6 +143,8 @@ class ModelConfig:
         d, f, v = self.d_model, self.d_ff, self.vocab
         hd, h, kv = self.hd, self.n_heads, self.n_kv_heads
         n = v * d * (1 if self.tie_embeddings else 2)
+        if self.dropless:
+            return self._dropless_counts(self.held)
         if self.family == "ssm":
             di, ns, dr = self.d_inner, self.ssm_state, self.dt_rank_
             per = (d * 2 * di + di * self.ssm_conv + di * (dr + 2 * ns)
@@ -112,6 +174,12 @@ class ModelConfig:
         """Active params per token (MoE: top_k of n_experts)."""
         if self.family != "moe":
             return self.param_count()
+        if self.dropless:
+            # top_k of all n_experts per token, of which a held share of
+            # top_k * held / n_experts on average (the expectation
+            # under routing) is computed here
+            return self._dropless_counts(
+                self.top_k * self.held / self.n_experts)
         d, f = self.d_model, self.d_ff
         full = self.param_count()
         moe_all = self.n_layers * self.n_experts * 3 * d * f

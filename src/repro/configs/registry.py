@@ -6,7 +6,7 @@ import importlib
 
 # assignment spellings (CLI: --arch <id>)
 ARCHS = (
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "phi3.5-moe-42b-a6.6b",
     "recurrentgemma-9b",
     "qwen2.5-32b",

@@ -133,13 +133,16 @@ def stats_grams(
     batch: Any,
     specs: Mapping[str, LinearSpec],
     bs: int,
-) -> Tuple[dict, dict, jax.Array]:
-    """Run one SU pass: returns (A_grams, G_grams, loss).
+) -> Tuple[dict, dict, jax.Array, dict]:
+    """Run one SU pass: returns (A_grams, G_grams, loss, extras), the
+    extras being the collected entries that are no factored linear's
+    (the expert layer's token counts).
 
     ``loss_with_taps(params, taps, batch) -> (loss, acts)`` where ``acts``
     maps each factored-linear name to its input activations
     (*stack, T, d_in) (or a precomputed blocked Gram, shape
-    (*stack, nb, bs, bs)).
+    (*stack, nb, bs, bs)). A ``grouped`` linear's tap gradient is its G
+    Gram already (``models/moe.share_ffn``).
     """
     loss, acts, tap_grads = _stats_pass(loss_with_taps, params, taps,
                                         batch)
@@ -150,8 +153,8 @@ def stats_grams(
         t = g.shape[-2]
         # Fisher convention: G = E_t[g g^T] * T (sum over tokens of the
         # batch-mean gradient outer products).
-        g_grams[name] = soi.blocked_gram(g, bs) * jnp.asarray(
-            t, jnp.float32)
+        g_grams[name] = g if spec.grouped else soi.blocked_gram(
+            g, bs) * jnp.asarray(t, jnp.float32)
         if spec.share_a_with is None:
             a = acts[name]
             if a.ndim >= 2 and a.shape[-1] == a.shape[-2] and a.ndim == len(
@@ -159,7 +162,8 @@ def stats_grams(
                 a_grams[name] = a                  # already a blocked gram
             else:
                 a_grams[name] = soi.blocked_gram(a, bs)
-    return a_grams, g_grams, loss
+    return a_grams, g_grams, loss, {k: v for k, v in acts.items()
+                                    if k not in specs}
 
 
 def stats_rank_k(

@@ -50,6 +50,11 @@ class LinearSpec:
     # Tap token dim is the MoE dispatch capacity rather than the raw
     # token count (per-expert buffers).
     cap_tokens: bool = False
+    # Per-expert factors over ragged token groups (the dropless layer,
+    # models/moe.share_ffn): the collected A is already the per-expert
+    # Gram over the expert's own token count, and the tap is the size of
+    # the G factor, its cotangent the per-expert G Gram itself.
+    grouped: bool = False
 
 
 def n_blocks(d: int, bs: int) -> int:

@@ -13,7 +13,9 @@ mapping argument):
                     the fused VMM⊕INV crossbar-group image (Sec. V)
 
 Beside them, ``flash_attention`` runs the model's causal self-attention
-by blocked online softmax on a TPU (forward, dq and dkv kernels).
+by blocked online softmax on a TPU (forward, dq and dkv kernels), and
+``grouped_matmul`` the dropless expert layer's grouped matmuls and
+per-expert Grams (megablox on a TPU).
 
 Validated in interpret mode on CPU against ``ref.py`` oracles
 (tests/test_kernels.py sweeps shapes/dtypes).

@@ -13,6 +13,9 @@ of a KV block. Blocks above the diagonal are skipped, their copy (the
 index maps clamp to the last block needed) and their compute; only the
 diagonal blocks build the mask.
 
+The value heads may be narrower than the query/key heads (latent
+attention: 192 and 128); the scale is the query/key width's.
+
 Numerics are the XLA path's: bf16 operands with f32 accumulation, the
 ``hd ** -0.5`` scale applied to the f32 scores, f32 softmax statistics,
 probabilities cast to the value dtype for PV (the backward casts
@@ -150,9 +153,10 @@ def _spec(rows, cols, index_map):
 
 
 def _fwd(q, k, v, b, interpret):
-    """q: (B, H, T, hd); k/v: (B, Hkv, T, hd). Returns the f32 output and
-    the rows' logsumexp, (B, H, 1, T)."""
+    """q/k: (B, H|Hkv, T, hd); v: (B, Hkv, T, dv). Returns the f32 output
+    (B, H, T, dv) and the rows' logsumexp, (B, H, 1, T)."""
     B, H, T, hd = q.shape
+    dv = v.shape[-1]
     g, n = H // k.shape[1], T // b
 
     def q_map(bi, h, i, j):
@@ -166,14 +170,14 @@ def _fwd(q, k, v, b, interpret):
         functools.partial(_fwd_kernel, scale=hd ** -0.5, n=n),
         grid=(B, H, n, n),
         in_specs=[_spec(b, hd, q_map), _spec(b, hd, kv_map),
-                  _spec(b, hd, kv_map)],
-        out_specs=[_spec(b, hd, q_map),
+                  _spec(b, dv, kv_map)],
+        out_specs=[_spec(b, dv, q_map),
                    _spec(1, b, lambda bi, h, i, j: (bi, h, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct((B, H, T, hd), _F32),
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), _F32),
                    jax.ShapeDtypeStruct((B, H, 1, T), _F32)],
         scratch_shapes=[pltpu.VMEM((b, MIN_BLOCK), _F32),
                         pltpu.VMEM((b, MIN_BLOCK), _F32),
-                        pltpu.VMEM((b, hd), _F32)],
+                        pltpu.VMEM((b, dv), _F32)],
         compiler_params=_PARAMS, interpret=interpret,
         name="flash_attention_fwd",
     )(q, k, v)
@@ -249,7 +253,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
 
 def _bwd(q, k, v, do, lse, di, b, interpret):
     B, H, T, hd = q.shape
-    hkv = k.shape[1]
+    hkv, dv = k.shape[1], v.shape[-1]
     g, n = H // hkv, T // b
     scale = hd ** -0.5
 
@@ -266,7 +270,7 @@ def _bwd(q, k, v, do, lse, di, b, interpret):
         functools.partial(_dq_kernel, scale=scale, n=n),
         grid=(B, H, n, n),
         in_specs=[_spec(b, hd, q_map), _spec(b, hd, kv_map),
-                  _spec(b, hd, kv_map), _spec(b, hd, q_map),
+                  _spec(b, dv, kv_map), _spec(b, dv, q_map),
                   _spec(1, b, stat_map), _spec(1, b, stat_map)],
         out_specs=_spec(b, hd, q_map),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -296,13 +300,13 @@ def _bwd(q, k, v, do, lse, di, b, interpret):
         functools.partial(_dkv_kernel, scale=scale, n=n, nr=nr),
         grid=(B, hkv, n, nr),
         in_specs=[_spec(b, hd, qd_map), _spec(b, hd, kd_map),
-                  _spec(b, hd, kd_map), _spec(b, hd, qd_map),
+                  _spec(b, dv, kd_map), _spec(b, dv, qd_map),
                   _spec(1, b, sd_map), _spec(1, b, sd_map)],
-        out_specs=[_spec(b, hd, kd_map), _spec(b, hd, kd_map)],
+        out_specs=[_spec(b, hd, kd_map), _spec(b, dv, kd_map)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((b, hd), _F32),
-                        pltpu.VMEM((b, hd), _F32)],
+                        pltpu.VMEM((b, dv), _F32)],
         compiler_params=_PARAMS, interpret=interpret,
         name="flash_attention_dkv",
     )(q, k, v, do, lse, di)
@@ -332,9 +336,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def causal_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            block: Optional[int] = None,
                            interpret: bool = False) -> jax.Array:
-    """Causal self-attention: q (B, T, H, hd), k/v (B, T, Hkv, hd), with
-    ``supports(T, T)`` and ``H % Hkv == 0``; returns (B, T, H, hd) in
-    q's dtype. ``block`` (queries and keys per tile, dividing T)
+    """Causal self-attention: q/k (B, T, H|Hkv, hd), v (B, T, Hkv, dv),
+    with ``supports(T, T)`` and ``H % Hkv == 0``; returns (B, T, H, dv)
+    in q's dtype. ``block`` (queries and keys per tile, dividing T)
     defaults to ``block_size(T)``."""
     T = q.shape[1]
     # (B, T, H, hd) -> (B, H, T, hd): the kernel's head-major layout

@@ -40,6 +40,7 @@ from repro.dist import sharding as shard_rules
 from repro.launch import compile_cache
 from repro.launch import steps as steps_mod
 from repro.launch.mesh import make_dev_mesh
+from repro.models import lm
 from repro.serve import (
     EngineConfig,
     PagedConfig,
@@ -319,6 +320,7 @@ def main(argv=None):
     compile_cache.enable()
     args = build_parser().parse_args(argv)
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
+    lm.check_serving(cfg)
     mesh = make_dev_mesh(args.model_parallel)
     obs = obs_mod.from_args(args)
     # vlm/audio prompts need modality inputs the engine doesn't take

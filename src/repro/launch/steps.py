@@ -234,6 +234,7 @@ def make_pipeline_step(cfg: ModelConfig, kcfg: KFACConfig, *,
         return make_train_step(cfg, kcfg, wu_plan=wu_plan)
     from repro import pipeline
 
+    lm.check_pipeline(cfg)
     if mesh is None:
         raise ValueError("pp > 1 needs a mesh with a 'stage' axis "
                          "(launch.mesh.make_pipeline_mesh)")
@@ -319,13 +320,35 @@ def make_stats_step(cfg: ModelConfig, kcfg: KFACConfig) -> Callable:
         def loss_with_taps(p, tp, bt):
             return mod.loss_fn(cfg, p, bt, taps=tp, collect=True)
 
-        a_grams, g_grams, loss = kfac.stats_grams(
+        a_grams, g_grams, loss, extras = kfac.stats_grams(
             loss_with_taps, state.params, taps, batch, specs,
             kcfg.block_size)
         kstate2 = kfac.update_factors(state.kfac, a_grams, g_grams, kcfg)
-        return state._replace(kfac=kstate2), {"stats_loss": loss}
+        metrics = {"stats_loss": loss}
+        metrics.update(moe_counters(extras))
+        return state._replace(kfac=kstate2), metrics
 
     return stats_step
+
+
+def moe_counters(extras: dict) -> dict:
+    """Counters of the expert layer on a statistics pass, from its held
+    experts' token counts (``<stack>/moe/counts``, (L, held)):
+    ``moe_held_tokens``, the (token, choice) pairs routed to the held
+    experts per layer, averaged over the layers; and
+    ``moe_busiest_over_mean``, the busiest held expert's count over the
+    held experts' mean, the largest over the layers. Empty for models
+    without the layer."""
+    counts = [v.astype(jnp.float32) for k, v in sorted(extras.items())
+              if k.endswith("/moe/counts")]
+    if not counts:
+        return {}
+    c = jnp.concatenate(counts)
+    per_layer = jnp.sum(c, axis=-1)
+    busiest = jnp.max(c, axis=-1) / jnp.maximum(
+        jnp.mean(c, axis=-1), 1e-9)
+    return {"moe_held_tokens": jnp.mean(per_layer),
+            "moe_busiest_over_mean": jnp.max(busiest)}
 
 
 def make_smw_step(cfg: ModelConfig, kcfg: KFACConfig,

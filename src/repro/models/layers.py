@@ -184,13 +184,14 @@ def _rotate_half(x: jax.Array) -> jax.Array:
 def _gqa_scores_to_out(q, k, v, mask, dt):
     """Dense-score attention for one (query-block, full-kv) pair.
 
-    q: (B, T, Hkv, G, hd); k/v: (B, S, Hkv, hd); mask: (B?, T, S) bool."""
+    q: (B, T, Hkv, G, hd); k: (B, S, Hkv, hd); v: (B, S, Hkv, dv);
+    mask: (B?, T, S) bool."""
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bthgd,bshd->bhgts", q, k,
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(mask[:, None, None, :, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgts,bshd->bthgd", p.astype(dt), v,
+    o = jnp.einsum("bhgts,bshe->bthge", p.astype(dt), v,
                    preferred_element_type=jnp.float32)
     return o.astype(dt)
 
@@ -203,9 +204,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """GQA attention with optional causality, sliding window, and
     query-chunking.
 
-    q: (B, T, H, hd); k/v: (B, S, Hkv, hd);
-    q_pos: (B, T) absolute positions; kv_pos: (B, S).
-    Returns (B, T, H, hd).
+    q: (B, T, H, hd); k: (B, S, Hkv, hd); v: (B, S, Hkv, dv) (latent
+    attention's value heads are narrower than its query/key heads; the
+    scale is ``hd ** -0.5``); q_pos: (B, T) absolute positions; kv_pos:
+    (B, S). Returns (B, T, H, dv).
 
     Two paths. Causal self-attention over the implicit positions
     ``arange(T)`` (``implicit_positions``: the caller's k/v are this
@@ -234,7 +236,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def _xla_attention(q, k, v, q_pos, kv_pos, causal, window, chunk):
     B, T, H, hd = q.shape
     S = k.shape[1]
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     g = H // hkv
     dt = q.dtype
     qg = q.reshape(B, T, hkv, g, hd)
@@ -274,10 +276,10 @@ def _xla_attention(q, k, v, q_pos, kv_pos, causal, window, chunk):
         body = jax.checkpoint(body)
         _, outs = jax.lax.scan(body, None, (qs, ps))
         out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(
-            B, Tp, hkv, g, hd)[:, :T]
+            B, Tp, hkv, g, dv)[:, :T]
     else:
         out = _gqa_scores_to_out(qg, k, v, mask_for(q_pos), dt)
-    return out.reshape(B, T, H, hd)
+    return out.reshape(B, T, H, dv)
 
 
 def kv_cache_update(cache_k, cache_v, k, v, idx):

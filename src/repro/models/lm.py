@@ -3,7 +3,11 @@
 One scanned-layer decoder with per-family mixer blocks:
 
   dense, vlm : global attention + SwiGLU MLP
-  moe        : global attention + top-k MoE FFN
+  moe        : global attention + top-k MoE FFN (capacity); or, with
+               ``cfg.dropless`` (DeepSeek-V3 / Moonlight), latent
+               attention (MLA) + shared experts + the held share of the
+               routed experts, after ``cfg.n_dense_layers`` leading
+               MLA + SwiGLU layers (a stack of their own, ``dense_layers``)
   ssm        : Mamba-1 mixer only (no MLP, d_ff = 0)
   hybrid     : RecurrentGemma pattern units (rec, rec, local-attn), each
                sub-layer followed by a SwiGLU MLP
@@ -74,8 +78,29 @@ def _init_attn(cfg, key) -> Dict:
     return p
 
 
-def _init_mlp(cfg, key) -> Dict:
-    d, f = cfg.d_model, cfg.d_ff
+def _init_mla(cfg, key) -> Dict:
+    """Latent attention in its training form: q straight from x; the
+    joint ``wkv_a`` gives the latent (``kv_lora_rank``) and the shared
+    rope key; ``wkv_b`` lifts the normed latent to per-head nope keys and
+    values."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    kv_b = h * (cfg.qk_nope_dim + cfg.v_head_dim)
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": jax.random.normal(ks[0], (d, h * cfg.qk_dim), jnp.float32)
+        * d ** -0.5,
+        "wkv_a": jax.random.normal(ks[1], (d, r + cfg.qk_rope_dim),
+                                   jnp.float32) * d ** -0.5,
+        "kv_norm": jnp.zeros((r,), jnp.float32),
+        "wkv_b": jax.random.normal(ks[2], (r, kv_b), jnp.float32)
+        * r ** -0.5,
+        "wo": jax.random.normal(ks[3], (h * cfg.v_head_dim, d),
+                                jnp.float32) * (h * cfg.v_head_dim) ** -0.5,
+    }
+
+
+def _init_mlp(cfg, key, width=None) -> Dict:
+    d, f = cfg.d_model, width or cfg.d_ff
     ks = jax.random.split(key, 3)
     return {
         "wg": jax.random.normal(ks[0], (d, f), jnp.float32) * d ** -0.5,
@@ -88,12 +113,19 @@ def _init_layer(cfg, kind: str, key) -> Dict:
     d = cfg.d_model
     ks = jax.random.split(key, 3)
     p: Dict[str, Any] = {"ln1": jnp.zeros((d,), jnp.float32)}
-    if kind in ("attn", "local"):
+    if cfg.mla and kind in ("attn", "moe"):
+        p["mla"] = _init_mla(cfg, ks[0])
+    elif kind in ("attn", "local", "moe"):
         p["attn"] = _init_attn(cfg, ks[0])
+    if kind in ("attn", "local"):
         p["ln2"] = jnp.zeros((d,), jnp.float32)
         p["mlp"] = _init_mlp(cfg, ks[1])
+    elif kind == "moe" and cfg.dropless:
+        p["ln2"] = jnp.zeros((d,), jnp.float32)
+        p["moe"] = moe_mod.init_share(cfg, ks[1])
+        p["shared"] = _init_mlp(cfg, ks[2],
+                                cfg.n_shared_experts * cfg.d_expert_)
     elif kind == "moe":
-        p["attn"] = _init_attn(cfg, ks[0])
         p["ln2"] = jnp.zeros((d,), jnp.float32)
         p["moe"] = moe_mod.init_moe(cfg, ks[1])
     elif kind == "mamba":
@@ -112,13 +144,24 @@ def layer_plan(cfg) -> Tuple[str, ...]:
     if cfg.family in ("dense", "vlm"):
         return ("attn",) * cfg.n_layers
     if cfg.family == "moe":
-        return ("moe",) * cfg.n_layers
+        return ("attn",) * cfg.n_dense_layers \
+            + ("moe",) * (cfg.n_layers - cfg.n_dense_layers)
     if cfg.family == "ssm":
         return ("mamba",) * cfg.n_layers
     if cfg.family == "hybrid":
         return tuple(cfg.pattern[i % len(cfg.pattern)]
                      for i in range(cfg.n_layers))
     raise ValueError(cfg.family)
+
+
+def stacks(cfg) -> Tuple[Tuple[str, str, int], ...]:
+    """(params key, layer kind, depth) of each scanned stack of a
+    non-hybrid model, in order: the leading dense layers
+    (``dense_layers``), then ``layers``."""
+    plan = layer_plan(cfg)
+    n_dense = cfg.n_dense_layers if cfg.family == "moe" else 0
+    lead = (("dense_layers", "attn", n_dense),) if n_dense else ()
+    return lead + (("layers", plan[-1], len(plan) - n_dense),)
 
 
 def _hybrid_split(cfg) -> Tuple[int, Tuple[str, ...]]:
@@ -156,10 +199,11 @@ def init(cfg, key) -> Dict:
         params["tail"] = {f"sub{i}": _init_layer(cfg, kind, tk[i])
                           for i, kind in enumerate(tail)}
     else:
-        kind = layer_plan(cfg)[0]
-        layer_keys = jax.random.split(ks[3], cfg.n_layers)
-        params["layers"] = jax.vmap(
-            lambda k: _init_layer(cfg, kind, k))(layer_keys)
+        for prefix, kind, n in stacks(cfg):
+            layer_keys = jax.random.split(
+                ks[3] if prefix == "layers" else ks[5], n)
+            params[prefix] = jax.vmap(
+                functools.partial(_init_layer, cfg, kind))(layer_keys)
     return params
 
 
@@ -264,21 +308,76 @@ def _attn_block(cfg, p, x, positions, ctx, prefix, *, window=0,
     return x + shard_acts(out), new_cache
 
 
+@jax.named_scope("mla")
+def _mla_block(cfg, p, x, positions, ctx, prefix, *,
+               implicit_positions=False):
+    """Pre-norm latent attention (DeepSeek-V2/V3 MLA) in its training
+    (non-absorbed) form: the nope keys and the values are lifted from
+    the normed latent per head; the rope key is one head shared by all.
+    Query/key heads are ``qk_nope_dim + qk_rope_dim`` wide, value heads
+    ``v_head_dim``; the score scale is the query/key width's. The rope
+    channels rotate by the repo's half split (``apply_rope``): the
+    published code de-interleaves them first, which is this model with
+    the rope columns of ``wq`` and ``wkv_a`` permuted."""
+    B, T, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    pm = p["mla"]
+    xin = rms_norm(x, p["ln1"], cfg.norm_eps)
+    q = dense(xin, pm["wq"], f"{prefix}/mla/wq", ctx)
+    kv_a = dense(xin, pm["wkv_a"], f"{prefix}/mla/wkv_a", ctx,
+                 collect_gram=False)
+    # the published latent norm keeps its module default eps, 1e-6
+    c_kv = rms_norm(kv_a[..., :r], pm["kv_norm"], 1e-6)
+    kv = dense(c_kv, pm["wkv_b"], f"{prefix}/mla/wkv_b", ctx)
+    q = q.reshape(B, T, h, nope + rope)
+    kv = kv.reshape(B, T, h, nope + dv)
+    q_pe = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    k_pe = apply_rope(kv_a[..., r:].reshape(B, T, 1, rope), positions,
+                      cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, h, rope))], axis=-1)
+    out = attention(q, k, kv[..., nope:], positions, positions,
+                    causal=True,
+                    chunk=cfg.attn_chunk if T > cfg.attn_chunk else 0,
+                    implicit_positions=implicit_positions)
+    out = dense(out.reshape(B, T, h * dv), pm["wo"], f"{prefix}/mla/wo",
+                ctx)
+    return x + shard_acts(out)
+
+
+def _ffn(cfg, p, xin, ctx, prefix, width):
+    """SwiGLU MLP ``p`` of ``width`` on the normed ``xin``."""
+    if p["wg"].shape[-1] < width:
+        xin = bwd_psum_if_bound(xin, MODEL)
+    g = dense(xin, p["wg"], f"{prefix}/wg", ctx)
+    u = dense(xin, p["wu"], f"{prefix}/wu", ctx, collect_gram=False)
+    f_loc = g.shape[-1]           # < width when wg/wu arrive model-sliced
+    hidden = swiglu(g, u)
+    hidden = shard_hint(hidden, BATCH_AXES, None, MODEL)
+    out = dense(hidden, p["wd"], f"{prefix}/wd", ctx)
+    if f_loc < width:
+        out = psum_if_bound(out, MODEL)
+    return shard_acts(out)
+
+
 @jax.named_scope("mlp")
 def _mlp_block(cfg, p, x, ctx, prefix):
     xin = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if p["mlp"]["wg"].shape[-1] < cfg.d_ff:
-        xin = bwd_psum_if_bound(xin, MODEL)
-    g = dense(xin, p["mlp"]["wg"], f"{prefix}/mlp/wg", ctx)
-    u = dense(xin, p["mlp"]["wu"], f"{prefix}/mlp/wu", ctx,
-              collect_gram=False)
-    f_loc = g.shape[-1]           # < d_ff when wg/wu arrive model-sliced
-    hidden = swiglu(g, u)
-    hidden = shard_hint(hidden, BATCH_AXES, None, MODEL)
-    out = dense(hidden, p["mlp"]["wd"], f"{prefix}/mlp/wd", ctx)
-    if f_loc < cfg.d_ff:
-        out = psum_if_bound(out, MODEL)
-    return x + shard_acts(out)
+    return x + _ffn(cfg, p["mlp"], xin, ctx, f"{prefix}/mlp", cfg.d_ff)
+
+
+def _share_moe_block(cfg, p, x, ctx, prefix):
+    """Shared experts (one SwiGLU MLP of ``n_shared_experts`` expert
+    widths, scope ``mlp``) plus the held share of the routed experts
+    (``moe.share_ffn``) on the same normed input."""
+    xin = rms_norm(x, p["ln2"], cfg.norm_eps)
+    routed = moe_mod.share_ffn(cfg, p["moe"], xin, ctx, f"{prefix}/moe")
+    with jax.named_scope("mlp"):
+        shared = _ffn(cfg, p["shared"], xin, ctx, f"{prefix}/shared",
+                      cfg.n_shared_experts * cfg.d_expert_)
+    return x + (routed + shared)
 
 
 def _layer_apply(cfg, kind, p, x, positions, ctx, prefix, cache=None,
@@ -290,6 +389,13 @@ def _layer_apply(cfg, kind, p, x, positions, ctx, prefix, cache=None,
     prefill: recurrent mixers gather their carried state at position
     state_len-1 instead of the padded tail (attention needs no such care
     — unwritten columns carry UNWRITTEN_POS and are mask-excluded)."""
+    if cfg.mla and kind in ("attn", "moe"):
+        x = _mla_block(cfg, p, x, positions, ctx, prefix,
+                       implicit_positions=implicit_positions)
+        if kind == "attn":
+            return _mlp_block(cfg, p, x, ctx, prefix), None
+    if kind == "moe" and cfg.dropless:
+        return _share_moe_block(cfg, p, x, ctx, prefix), None
     if kind in ("attn", "local"):
         window = cfg.window if kind == "local" else 0
         x, nc = _attn_block(cfg, p, x, positions, ctx, prefix,
@@ -371,9 +477,8 @@ def _scan_layers(cfg, params, x, positions, taps, collect, cache, idx,
     """Run all layers; returns (x, stats, new_cache)."""
     stats_out: Dict[str, jax.Array] = {}
 
-    def run_seq(prefix, stacked, n, x, cache_tree):
-        """Scan over ``n`` stacked layers of uniform kind."""
-        kind = layer_plan(cfg)[0]
+    def run_seq(prefix, kind, stacked, x, cache_tree):
+        """Scan over the stacked layers of uniform ``kind``."""
 
         def body(xcur, xs):
             p_l, taps_l, cache_l = xs
@@ -443,11 +548,12 @@ def _scan_layers(cfg, params, x, positions, taps, collect, cache, idx,
         if cache is not None:
             new_cache = {"units": ncache_units, "tail": ncache_tail}
     else:
-        layer_cache = cache.get("layers") if cache else None
-        x, ncache = run_seq("layers", params["layers"], cfg.n_layers, x,
-                            layer_cache)
-        if cache is not None:
-            new_cache = {"layers": ncache}
+        for prefix, kind, _ in stacks(cfg):
+            layer_cache = cache.get(prefix) if cache else None
+            x, ncache = run_seq(prefix, kind, params[prefix], x,
+                                layer_cache)
+            if cache is not None:
+                new_cache = dict(new_cache or {}, **{prefix: ncache})
     return x, stats_out, new_cache
 
 
@@ -530,6 +636,15 @@ def loss_fn(cfg, params, batch, taps=None, collect=False):
 # Per-stage slices (pipeline parallelism, repro.pipeline)
 # ---------------------------------------------------------------------------
 
+def check_pipeline(cfg):
+    """Refuse what the pipeline stage program cannot run."""
+    if cfg.dropless or cfg.n_dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: the pipeline stage program has no dropless expert "
+            f"exchange (the all-to-all of the expert-share layer) and no "
+            f"stage for the leading dense stack")
+
+
 def embed_inputs(cfg, params, batch, positions):
     """Stage-0 front of the pipelined forward: token embedding (+ VLM
     image projection). Public alias of the internal embed so the
@@ -556,6 +671,7 @@ def stage_slice_forward(cfg, layer_stack, x, positions, *, train=True,
     if cfg.family == "audio":
         raise NotImplementedError(
             "audio stacks pipeline through whisper.stage_slice_forward")
+    check_pipeline(cfg)
     if cfg.family == "hybrid":
         def body(xcur, xs):
             p_u, ok = xs
@@ -611,7 +727,20 @@ def head_loss(cfg, params, x, batch):
 # Serving
 # ---------------------------------------------------------------------------
 
+_NO_LATENT_CACHE = (
+    "latent attention (MLA) has no serving cache here: it needs a latent "
+    "cache (kv_lora_rank + qk_rope_dim per token), which the KV pools "
+    "(serve/pool, serve/paged) do not have")
+
+
+def check_serving(cfg):
+    """Refuse what the serving caches cannot hold."""
+    if cfg.mla:
+        raise NotImplementedError(f"{cfg.name}: {_NO_LATENT_CACHE}")
+
+
 def init_cache(cfg, batch: int, seq_len: int, dtype=jnp.bfloat16) -> Dict:
+    check_serving(cfg)
     kv, hd = cfg.n_kv_heads, cfg.hd
 
     def attn_cache(S):
@@ -705,14 +834,38 @@ def kfac_specs(cfg) -> Dict[str, LinearSpec]:
         if with_mlp:
             mlp(prefix, stack)
 
-    def mlp(prefix, stack):
-        specs[f"{prefix}/mlp/wg"] = LinearSpec(d, f, stack)
-        specs[f"{prefix}/mlp/wu"] = LinearSpec(
-            d, f, stack, share_a_with=f"{prefix}/mlp/wg")
-        specs[f"{prefix}/mlp/wd"] = LinearSpec(f, d, stack)
+    def mlp(prefix, stack, width=f, sub="mlp"):
+        specs[f"{prefix}/{sub}/wg"] = LinearSpec(d, width, stack)
+        specs[f"{prefix}/{sub}/wu"] = LinearSpec(
+            d, width, stack, share_a_with=f"{prefix}/{sub}/wg")
+        specs[f"{prefix}/{sub}/wd"] = LinearSpec(width, d, stack)
+
+    def mla(prefix, stack):
+        # kv_a shares q's input Gram; kv_b's A is the normed latent's
+        r, rope = cfg.kv_lora_rank, cfg.qk_rope_dim
+        dv = cfg.v_head_dim
+        specs[f"{prefix}/mla/wq"] = LinearSpec(d, h * cfg.qk_dim, stack)
+        specs[f"{prefix}/mla/wkv_a"] = LinearSpec(
+            d, r + rope, stack, share_a_with=f"{prefix}/mla/wq")
+        specs[f"{prefix}/mla/wkv_b"] = LinearSpec(
+            r, h * (cfg.qk_nope_dim + dv), stack)
+        specs[f"{prefix}/mla/wo"] = LinearSpec(h * dv, d, stack)
 
     if cfg.family in ("dense", "vlm"):
         attn_mlp("layers", (cfg.n_layers,))
+    elif cfg.dropless:
+        if cfg.n_dense_layers:
+            stack = (cfg.n_dense_layers,)
+            mla("dense_layers", stack)
+            mlp("dense_layers", stack)
+        L, fe = cfg.n_moe_layers, cfg.d_expert_
+        mla("layers", (L,))
+        mlp("layers", (L,), cfg.n_shared_experts * fe, "shared")
+        stack = (L, cfg.held)
+        specs["layers/moe/wg"] = LinearSpec(d, fe, stack, grouped=True)
+        specs["layers/moe/wu"] = LinearSpec(
+            d, fe, stack, share_a_with="layers/moe/wg", grouped=True)
+        specs["layers/moe/wd"] = LinearSpec(fe, d, stack, grouped=True)
     elif cfg.family == "moe":
         L = cfg.n_layers
         attn_mlp("layers", (L,), with_mlp=False)
@@ -758,9 +911,16 @@ def kfac_specs(cfg) -> Dict[str, LinearSpec]:
 
 
 def build_taps(cfg, specs: Dict[str, LinearSpec], n_tokens: int) -> Dict:
-    """Zero taps sized for a stats pass over ``n_tokens`` tokens."""
+    """Zero taps sized for a stats pass over ``n_tokens`` tokens; a
+    grouped (per-expert) linear's tap is the size of its G factor."""
+    from repro.core import soi
+
     out = {}
     for name, s in specs.items():
+        if s.grouped:
+            out[name] = jnp.zeros(
+                soi.factor_shapes(s, cfg.soi_block)["G"], jnp.float32)
+            continue
         t = moe_mod.capacity(cfg, n_tokens) if s.cap_tokens else n_tokens
         out[name] = jnp.zeros(s.stack + (t, s.d_out), jnp.float32)
     return out

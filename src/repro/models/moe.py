@@ -1,4 +1,23 @@
-"""Mixture-of-Experts FFN: capacity-based dispatch, two datapaths.
+"""Mixture-of-Experts FFN: capacity-based dispatch (two datapaths), and
+the dropless expert-share layer.
+
+The expert-share layer (:func:`share_ffn`, ``cfg.dropless``; DeepSeek-V3
+/ Moonlight) is told which experts it holds (``cfg.expert_first`` and
+``cfg.experts_held`` of ``cfg.n_experts``), routes every token over all
+of them (:func:`route`), and computes its own experts' part of the
+result: the (token, choice) pairs are sorted by held expert and run
+through grouped matmuls (``kernels/grouped_matmul``) whose row groups
+are the held experts' token counts. No capacity, no drop: the buffer is
+sized for the worst case (every choice of every token held here), and
+the rows no token fills are skipped. On one device the layer runs
+without its exchange; a mesh whose ``model`` axis is larger than one, or
+the pipeline stage program, refuses it (the exchange is not written).
+Its K-FAC factors are per held expert over that expert's ragged token
+group: ``A_e = sum_{t->e} x x^T / n_e`` (collected in the forward) and
+``G_e = sum_{t->e} g g^T`` (the cotangent of a tap the size of the
+factor, :func:`_gram_tap`).
+
+The capacity layer (:func:`moe_ffn`):
 
 * **Fast path** (training/serving forward, no stats collection):
   ``shard_map`` expert parallelism. Experts are sharded over the
@@ -31,7 +50,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.api import BATCH_AXES, MODEL, fwd_psum, shard_hint
+from repro.core import soi
+from repro.dist.api import (
+    BATCH_AXES,
+    MODEL,
+    active_mesh,
+    fwd_psum,
+    in_hint_guard,
+    shard_hint,
+)
+from repro.kernels import grouped_matmul
 from repro.models.layers import Ctx, cast, dense_stacked, swiglu
 
 
@@ -245,3 +273,162 @@ def moe_ffn(cfg, p: Dict, x: jax.Array, ctx: Optional[Ctx],
     out = jnp.zeros((nt, D), x.dtype).at[tok].add(gathered * w[:, None])
     out = shard_hint(out, BATCH_AXES, MODEL)
     return out.reshape(B, T, D)
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert-share layer
+# ---------------------------------------------------------------------------
+
+def init_share(cfg, key) -> Dict:
+    """Router over all ``n_experts`` (and its correction bias, zero) and
+    the held experts' weights; expert ``e``'s weights come from
+    ``fold_in(key_experts, e)``, so a share holds what the whole layer
+    holds of its experts."""
+    d, f, e = cfg.d_model, cfg.d_expert_, cfg.n_experts
+    kr, ke = jax.random.split(key)
+
+    def expert(i):
+        ks = jax.random.split(jax.random.fold_in(ke, i), 3)
+        return (jax.random.normal(ks[0], (d, f), jnp.float32) * d ** -0.5,
+                jax.random.normal(ks[1], (d, f), jnp.float32) * d ** -0.5,
+                jax.random.normal(ks[2], (f, d), jnp.float32) * f ** -0.5)
+
+    wg, wu, wd = jax.vmap(expert)(cfg.expert_first + jnp.arange(cfg.held))
+    return {
+        "router": jax.random.normal(kr, (d, e), jnp.float32) * d ** -0.5,
+        "bias": jnp.zeros((e,), jnp.float32),
+        "wg": wg, "wu": wu, "wd": wd,
+    }
+
+
+def route(cfg, p: Dict, xf: jax.Array):
+    """(weights (N, K) f32, expert ids (N, K)) over all ``n_experts``,
+    as DeepSeek-V3's ``noaux_tc`` with one group routes: float32
+    logits, sigmoid scores; the top ``K`` of score + correction bias are
+    chosen, their weights are the unbiased scores, normalized over the
+    ``K`` and scaled by ``routed_scale``. The bias has no gradient."""
+    logits = jnp.dot(xf.astype(jnp.float32),
+                     p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + jax.lax.stop_gradient(p["bias"])
+    _, eid = jax.lax.top_k(choice, cfg.top_k)
+    w = jnp.take_along_axis(scores, eid, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scale, eid
+
+
+@jax.custom_vjp
+def _permute(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` whose inverse is ``inv``; the
+    backward gathers by ``inv`` (no scatter)."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _permute_fwd(x, idx, inv):
+    return jnp.take(x, idx, axis=0), (idx, inv)
+
+
+def _permute_bwd(res, ct):
+    idx, inv = res
+    return jnp.take(ct, inv, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gram_tap(y, z, sizes, cap):
+    """Identity on ``y``; the cotangent of ``z`` (a zero tap the shape of
+    the held experts' G factor) is each expert's blocked Gram of ``y``'s
+    cotangent over its rows: ``G_e = sum_{t->e} g g^T``."""
+    del z, sizes
+    return y
+
+
+def _gram_tap_fwd(y, z, sizes, cap):
+    del z
+    return y, sizes
+
+
+def _gram_tap_bwd(cap, sizes, ct):
+    bs = soi.block_size_for(ct.shape[-1], cap)
+    return ct, grouped_matmul.group_gram(ct, sizes, bs), None
+
+
+_gram_tap.defvjp(_gram_tap_fwd, _gram_tap_bwd)
+
+
+def _grouped_linear(x, w, sizes, name, ctx: Optional[Ctx],
+                    collect_gram: bool = True):
+    """Held experts' ``x @ w[e]`` on sorted rows, with the per-expert A
+    Gram collected and the G tap applied (module doc)."""
+    with jax.named_scope("moe_experts"):
+        y = grouped_matmul.gmm(x, w, sizes)
+    if ctx is None:
+        return y
+    if ctx.collect == "cols":
+        raise NotImplementedError(
+            f"{name}: the rank-k (SMW) statistics path has no per-expert "
+            f"columns for ragged token groups")
+    if ctx.collect and collect_gram:
+        bs = soi.block_size_for(x.shape[-1], ctx.soi_block)
+        n = jnp.maximum(sizes[:-1], 1).astype(jnp.float32)
+        ctx.stats[name] = grouped_matmul.group_gram(x, sizes, bs) \
+            / n[:, None, None, None]
+    if ctx.taps is not None and name in ctx.taps:
+        y = _gram_tap(y, ctx.taps[name], sizes, ctx.soi_block)
+    return y
+
+
+def _check_share_scope(prefix):
+    if in_hint_guard():
+        raise NotImplementedError(
+            f"{prefix}: the dropless expert layer has no exchange inside "
+            f"the pipeline stage program (its tokens would need the "
+            f"dropless all-to-all, which is not written)")
+    mesh = active_mesh()
+    if mesh is not None and dict(mesh.shape).get(MODEL, 1) > 1:
+        raise NotImplementedError(
+            f"{prefix}: the dropless expert layer runs one device's share "
+            f"without its exchange; a '{MODEL}' axis of "
+            f"{dict(mesh.shape)[MODEL]} needs the dropless all-to-all, "
+            f"which is not written")
+
+
+def share_ffn(cfg, p: Dict, x: jax.Array, ctx: Optional[Ctx],
+              prefix: str) -> jax.Array:
+    """x: (B, T, D) -> (B, T, D), the held experts' part of the routed
+    result (module doc). Under ``ctx.collect`` the held experts' token
+    counts are kept as ``<prefix>/counts``."""
+    _check_share_scope(prefix)
+    B, T, D = x.shape
+    K, H = cfg.top_k, cfg.held
+    n = B * T * K
+    m = -(-n // grouped_matmul.ROW_TILE) * grouped_matmul.ROW_TILE
+    xf = x.reshape(B * T, D)
+    with jax.named_scope("moe_route"):
+        w, eid = route(cfg, p, xf)
+        lid = eid - cfg.expert_first
+        mine = (lid >= 0) & (lid < H)
+        # held experts first, in order; then the choices held elsewhere
+        key = jnp.where(mine, lid, H).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        inv = jnp.argsort(order)
+        counts = jnp.bincount(key, length=H + 1).astype(jnp.int32)
+        sizes = counts.at[H].add(m - n)
+        buf = _permute(jnp.repeat(xf, K, axis=0), order, inv)
+        buf = jnp.pad(buf, ((0, m - n), (0, 0)))
+    g = _grouped_linear(buf, p["wg"], sizes, f"{prefix}/wg", ctx)
+    u = _grouped_linear(buf, p["wu"], sizes, f"{prefix}/wu", ctx,
+                        collect_gram=False)
+    with jax.named_scope("moe_experts"):
+        h = swiglu(g, u)
+    y = _grouped_linear(h, p["wd"], sizes, f"{prefix}/wd", ctx)
+    with jax.named_scope("moe_route"):
+        y = _permute(y[:n], inv, order).reshape(B * T, K, D)
+        wm = jnp.where(mine, w, 0.0)
+        out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), wm)
+    if ctx is not None and ctx.collect:
+        ctx.stats[f"{prefix}/counts"] = counts[:H]
+    return out.astype(x.dtype).reshape(B, T, D)

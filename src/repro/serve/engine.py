@@ -183,7 +183,9 @@ class ServeEngine:
                 "the continuous-batching engine currently serves "
                 "token-only prompt families (dense/moe/ssm/hybrid)")
         from repro.launch import steps as steps_mod
+        from repro.models import lm
 
+        lm.check_serving(cfg)
         if ecfg.quant not in ("none", "int8"):
             raise ValueError(f"unknown quant mode {ecfg.quant!r}; "
                              "one of ('none', 'int8')")

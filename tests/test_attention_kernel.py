@@ -87,6 +87,42 @@ def test_kernel_matches_xla_attention(h, hkv, block, hd):
         assert _rel(a, b) < GRAD_TOL, name
 
 
+@pytest.mark.parametrize("dqk, dv", [(192, 128), (64, 64)],
+                         ids=["mla-192-128", "equal-64"])
+def test_kernel_value_width_apart_from_query_key(dqk, dv):
+    """Value heads narrower than the query/key heads (latent attention:
+    192 and 128, scale 192 ** -0.5, no power of two), two 128 blocks a
+    side, against the XLA path; and the equal widths through the same
+    entry. Tolerances as above: the narrower value heads change no
+    rounding step, only the widths of the PV and dV products."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (B, T, 4, dqk), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, T, 4, dqk), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, T, 4, dv), jnp.bfloat16)
+    ct = jax.random.normal(ks[3], (B, T, 4, dv), jnp.float32)
+    pos = _pos()
+
+    def xla(q, k, v):
+        return attention(q, k, v, pos, pos)
+
+    def kernel(q, k, v):
+        return flash_attention.causal_flash_attention(
+            q, k, v, block=128, interpret=True)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
+                                       * ct)
+
+    want, got = jax.jit(xla)(q, k, v), jax.jit(kernel)(q, k, v)
+    assert got.shape == want.shape == (B, T, 4, dv)
+    assert _rel(got, want) < OUT_TOL
+    g_want = jax.jit(jax.grad(loss(xla), argnums=(0, 1, 2)))(q, k, v)
+    g_got = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(q, k, v)
+    for name, a, b in zip("qkv", g_got, g_want):
+        assert a.shape == b.shape, name
+        assert _rel(a, b) < GRAD_TOL, name
+
+
 def _cfg():
     return dataclasses.replace(get_smoke_config("qwen2-0.5b"), head_dim=HD)
 

@@ -49,7 +49,7 @@ def test_stats_grams_match_manual():
     y = jnp.asarray(r.standard_normal((T, 4)), jnp.float32)
     taps = {"w1": jnp.zeros((T, 8)), "w2": jnp.zeros((T, 4))}
 
-    a_grams, g_grams, loss = kfac.stats_grams(
+    a_grams, g_grams, loss, _ = kfac.stats_grams(
         loss_with_taps, params, taps, (x, y), specs, bs=8)
 
     # A factor: E[a a^T] per block (block-padded to bs=8; d_in=6 live)

@@ -41,7 +41,7 @@ def _batch(cfg, b=2, t=16):
 
 
 @pytest.mark.parametrize("arch", ["qwen2-0.5b", "falcon-mamba-7b",
-                                  "moonshot-v1-16b-a3b",
+                                  "moonlight-16b-a3b",
                                   "whisper-tiny"])
 def test_train_stats_inv_steps_run(arch):
     cfg = get_smoke_config(arch)

@@ -36,7 +36,7 @@ def test_fast_path_selection():
 
 
 def test_reference_path_capacity_and_drop():
-    cfg = get_smoke_config("moonshot-v1-16b-a3b")
+    cfg = get_smoke_config("phi3.5-moe-42b-a6.6b")
     params = lm.init(cfg, jax.random.PRNGKey(0))
     r = np.random.default_rng(1)
     batch = {"tokens": jnp.asarray(
@@ -46,7 +46,7 @@ def test_reference_path_capacity_and_drop():
 
 
 def test_moe_capacity_math():
-    cfg = get_smoke_config("moonshot-v1-16b-a3b")
+    cfg = get_smoke_config("phi3.5-moe-42b-a6.6b")
     c = moe.capacity(cfg, 1024)
     assert c >= 8 and c % 8 == 0
     expect = cfg.capacity_factor * 1024 * cfg.top_k / cfg.n_experts

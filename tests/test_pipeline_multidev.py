@@ -272,7 +272,7 @@ def test_pp2_moe_and_ssm_one_step():
     capacity semantics — vs the meshless reference's global pool)."""
     mesh = make_pipeline_mesh(2)
     for arch, tol in (("falcon-mamba-7b", 1e-5),
-                      ("moonshot-v1-16b-a3b", 2e-2)):
+                      ("phi3.5-moe-42b-a6.6b", 2e-2)):
         cfg = dataclasses.replace(get_smoke_config(arch),
                                   dtype="float32", train_accum=2)
         mod = steps_mod.model_module(cfg)

@@ -67,3 +67,11 @@ def test_engine_path_serves_trace():
     assert all(1 <= n <= 6 for n in budgets.values())
     assert summary["generated_tokens"] == sum(budgets.values())
     assert summary["decode_tok_per_s"] > 0
+
+
+def test_latent_attention_arch_is_refused():
+    """Serving MLA needs a latent cache the KV pools do not have: the CLI
+    says so before it builds anything."""
+    with pytest.raises(NotImplementedError, match="latent cache"):
+        main(["--arch", "moonlight-16b-a3b", "--smoke", "--batch", "2",
+              "--prompt-len", "8", "--gen", "4"])

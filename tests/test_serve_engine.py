@@ -441,3 +441,13 @@ def test_engine_int8_quantized_residency():
 
     with pytest.raises(ValueError):
         ServeEngine(cfg, params, EngineConfig(quant="int4"))
+
+
+def test_engine_refuses_latent_attention():
+    """The engine refuses an MLA model, naming the latent cache it would
+    need."""
+    from repro.serve.engine import EngineConfig, ServeEngine
+
+    cfg = get_smoke_config("moonlight-16b-a3b")
+    with pytest.raises(NotImplementedError, match="latent cache"):
+        ServeEngine(cfg, {}, EngineConfig())

@@ -232,7 +232,7 @@ def test_stats_rank_k_grams_bitwise_vs_stats_grams():
              jnp.asarray(r.standard_normal((T, 4)), jnp.float32))
     taps = {"w1": jnp.zeros((T, 8)), "w2": jnp.zeros((T, 4))}
 
-    a_ref, g_ref, loss_ref = kfac.stats_grams(
+    a_ref, g_ref, loss_ref, _ = kfac.stats_grams(
         make_loss(True), params, taps, batch, specs, bs=8)
     a_rk, g_rk, cols, loss_rk = kfac.stats_rank_k(
         make_loss("cols"), params, taps, batch, specs, bs=8)

@@ -131,6 +131,45 @@ def test_flash_attention_compiles_for_v5e(one_chip, hd):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+def test_flash_attention_compiles_for_v5e_at_moonlight(one_chip):
+    """Moonlight's MLA attention: 16 heads, query/key width 192, value
+    width 128, one row of 8,192 tokens (eight 1024 blocks a side)."""
+    from repro.kernels.flash_attention import causal_flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(causal_flash_attention(q, k, v).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((1, 8192, 16, d), jnp.bfloat16,
+                                 sharding=one_chip) for d in (192, 192, 128)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_grouped_matmuls_compile_for_v5e_at_moonlight(one_chip):
+    """The expert layer's grouped matmuls (forward and megablox's
+    backward) and its per-expert Grams at Moonlight's widths: one 8k row
+    of 6 choices over 8 held experts, 2,048 and 1,408 wide, and 128-wide
+    K-FAC blocks."""
+    from repro.kernels import grouped_matmul
+
+    m, d, f, e = 8192 * 6, 2048, 1408, 8
+
+    def loss(x, wg, wd, sizes):
+        h = grouped_matmul.gmm(x, wg, sizes)
+        y = grouped_matmul.gmm(h, wd, sizes)
+        grams = grouped_matmul.group_gram(h, sizes, 128)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(grams)
+
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((m, d), jnp.bfloat16), ((e, d, f), jnp.float32),
+        ((e, f, d), jnp.float32), ((e + 1,), jnp.int32))]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    # forward 2, gram 1, backward 2 x (gmm + tgmm)
+    assert text.count('custom_call_target="tpu_custom_call"') == 7
+
+
 def _custom_call_stacks(text):
     """The name stack (``op_name``) of each Mosaic custom call of a
     compiled program; an instruction's text runs to the next one."""

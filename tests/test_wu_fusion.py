@@ -183,7 +183,7 @@ def test_apply_updates_pooled_bitwise(pool_elementwise):
     assert int(s_got.step) == int(s_ref.step)
 
 
-@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "moonshot-v1-16b-a3b"])
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "moonlight-16b-a3b"])
 def test_train_step_fused_bitwise_on_arch(arch):
     """The launch-layer wiring: make_train_step(wu_plan=...) on real
     smoke archs (dense + MoE-stacked) is bitwise the legacy step."""
