@@ -2,7 +2,15 @@
 least time is the larger of (FLOPs / bf16 peak) and (bytes / HBM peak)
 for the gate, up and down products of the rows routed here at the
 expected share, forward and backward (``moe_counts.expert_work``); the
-measured time is the train program's ``moe_experts`` scope."""
+measured time is the train program's ``moe_experts`` scope.
+
+The rows are the expectation under routing, ``top_k * experts_held /
+n_experts`` per token, not the rows the run routed here: the program
+counts those only on its statistics steps (``moe_held_tokens``), which no
+reader is handed. A window's held rows differ from the expectation by
+the topics its rows draw (one 8k row holds one topic's 512 ids, whose
+tokens route alike), so the share moves with the seed by as much as the
+held load does (PERF.md §3)."""
 
 import counts
 import moe_counts

@@ -1,14 +1,14 @@
 """Device time by the scopes of the latent-attention and expert-share
 model (``models/lm._mla_block``, ``models/moe.share_ffn``,
-``kernels/grouped_matmul``), read from a profiler trace as
-``scopes.py`` reads its own.
+``kernels/grouped_matmul``), read from a profiler trace by ``scopes.py``.
 
 ``scopes.SCOPES`` knows six scopes; this module adds ``mla``,
-``moe_route``, ``moe_experts`` and ``expert_gram`` to them and decodes
-the XSpace with ``scopes``' own functions. Each operation is charged to
-the innermost scope of all ten in its name stack, per program, and, when
-it is a Pallas call (``pallas_call`` in its name stack), to that scope's
-kernels as well (``<scope>/kernel``).
+``moe_route``, ``moe_experts`` and ``expert_gram``, and a kernel flag:
+an operation that is a Pallas call (``pallas_call`` in its name stack)
+is charged to its scope and to that scope's kernels (``<scope>/kernel``).
+``load`` is ``scopes.load`` run with this module's ``scope_of`` in place
+of its own; everything else (the decoding, the window, the charging per
+program) is ``scopes``'.
 
 ``split(reading)`` returns None, and never raises, where the trace cannot
 be found or decoded, or where no operation carries a scope.
@@ -16,105 +16,67 @@ be found or decoded, or where no operation carries a scope.
 
 from __future__ import annotations
 
-import gzip
+import contextlib
 import re
 import traceback
-from collections import defaultdict
 from typing import Dict, Optional
 
 import scopes
-import trace_reduce
 
 SCOPES = scopes.SCOPES + ("mla", "moe_route", "moe_experts", "expert_gram")
 _COMPONENT = re.compile(r"^(?:\w+\()*(%s)\)*$" % "|".join(SCOPES))
 #: the name-stack component of an operation a Pallas kernel lowers to
 KERNEL = "pallas_call"
-
-
-def scope_of(stack: str) -> Optional[str]:
-    """The innermost scope of ``SCOPES`` in a name stack; None for none."""
-    stack = stack.split(";")[0]
-    if not scopes._STACK.match(stack):
-        return None
-    found = None
-    for c in stack.split("/")[1:]:
-        m = _COMPONENT.match(c)
-        if m and not c.startswith("jit("):
-            found = m.group(1)
-    return found
+_KERNEL_TAG = "/kernel"
+_scope_of = scopes.scope_of
 
 
 def _is_kernel(stack: str) -> bool:
     return KERNEL in stack.split(";")[0].rsplit(":", 1)[0].split("/")
 
 
-def _window(space):
-    window = None
-    for plane in space.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        names = {e.key: e.value.name for e in plane.event_metadata}
-        for line in plane.lines:
-            for ev in line.events:
-                if names.get(ev.metadata_id) == trace_reduce.WINDOW:
-                    s = line.timestamp_ns * 1e-9 + ev.offset_ps * 1e-12
-                    iv = (s, s + ev.duration_ps * 1e-12)
-                    if window is None or iv[1] - iv[0] > \
-                            window[1] - window[0]:
-                        window = iv
-    return window
+@contextlib.contextmanager
+def _names(**more):
+    """``scopes`` with this module's scope names (and ``more`` of its
+    globals) in place of its own, for the time of the block."""
+    swap = dict(_COMPONENT=_COMPONENT, **more)
+    saved = {k: getattr(scopes, k) for k in swap}
+    for k, v in swap.items():
+        setattr(scopes, k, v)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            setattr(scopes, k, v)
+
+
+def scope_of(stack: str) -> Optional[str]:
+    """The innermost scope of ``SCOPES`` in a name stack (``scopes``'
+    rule over this module's names), tagged ``<scope>/kernel`` for a
+    Pallas call; None for none."""
+    with _names():
+        found = _scope_of(stack)
+    if found is not None and _is_kernel(stack):
+        found += _KERNEL_TAG
+    return found
 
 
 def load(path: str) -> Optional[Dict[str, Dict[str, float]]]:
     """program -> scope (and ``<scope>/kernel``) -> device seconds in the
     traced window, averaged over the TPU devices."""
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rb") as f:
-        space = scopes._xspace_class().FromString(f.read())
-    window = _window(space)
-    out: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(
-        float))
-    n_dev = 0
-    for plane in space.planes:
-        if not trace_reduce.DEVICE_PLANE.match(plane.name):
-            continue
-        n_dev += 1
-        stat_names = {e.key: e.value.name for e in plane.stat_metadata}
-        meta = {e.key: e.value for e in plane.event_metadata}
-        lines = {line.name: line for line in plane.lines}
-        programs = {}
-        for ev in getattr(lines.get("XLA Modules"), "events", ()):
-            m = scopes._MODULE.match(meta[ev.metadata_id].name)
-            if m:
-                programs[int(m.group(2))] = m.group(1)
-        ops = lines.get("XLA Ops")
-        charge = {}            # metadata id -> (program, scope, kernel?)
-        for ev in getattr(ops, "events", ()):
-            if ev.metadata_id not in charge:
-                md = meta[ev.metadata_id]
-                stats = {stat_names.get(s.metadata_id): s
-                         for s in md.stats}
-                tf_op, pid = stats.get("tf_op"), stats.get("program_id")
-                scope = None if trace_reduce.CONTAINER.match(md.name) \
-                    or tf_op is None else scope_of(tf_op.str_value)
-                charge[ev.metadata_id] = (
-                    programs.get(pid.uint64_value if pid else None, "?"),
-                    scope, scope is not None and _is_kernel(
-                        tf_op.str_value))
-            program, scope, kernel = charge[ev.metadata_id]
-            if scope is None:
-                continue
-            s = ops.timestamp_ns * 1e-9 + ev.offset_ps * 1e-12
-            e = s + ev.duration_ps * 1e-12
-            if window is not None:
-                s, e = max(s, window[0]), min(e, window[1])
-            if e > s:
-                out[program][scope] += e - s
-                if kernel:
-                    out[program][scope + "/kernel"] += e - s
-    if not out:
+    with _names(scope_of=scope_of):
+        got = scopes.load(path)
+    if got is None:
         return None
-    return {p: {k: v / n_dev for k, v in d.items()} for p, d in out.items()}
+    out = {}
+    for program, by_scope in got.items():
+        d = dict(by_scope)
+        for k, v in by_scope.items():
+            if k.endswith(_KERNEL_TAG):
+                base = k[:-len(_KERNEL_TAG)]
+                d[base] = d.get(base, 0.0) + v
+        out[program] = d
+    return out
 
 
 def split(reading) -> Optional[Dict[str, Dict[str, float]]]:
